@@ -9,14 +9,14 @@ scan body, zero host transfer per round).  Reported per device count:
 * ``per_round_ms``   — its inverse, the per-round wall-clock.
 
 XLA fixes the device count at backend init, so each device count runs in
-a **child process** with ``XLA_FLAGS=--xla_force_host_platform_device_
-count=N``; the parent aggregates the children's CSV into
-``BENCH_fig10.json`` (children print raw lines and never write JSON
-themselves, so concurrent device counts cannot clobber one file).  On
-simulated host devices all "devices" share the same CPU cores, so this
-measures the *mechanics* (shard_map program, collective schedule,
-padding) rather than real scaling — on a TPU slice the same flag-free
-invocation shards over the actual chips.
+a **child process** on the CPU backend (``JAX_PLATFORMS=cpu``,
+``XLA_FLAGS=--xla_force_host_platform_device_count=N``); the parent
+aggregates the children's CSV into ``BENCH_fig10.json`` (children print
+raw lines and never write JSON themselves, so concurrent device counts
+cannot clobber one file).  All simulated "devices" share the same CPU
+cores, so this measures the *mechanics* (shard_map program, collective
+schedule, padding), not scaling on a chip: every record says
+``platform: cpu``.
 """
 from __future__ import annotations
 
@@ -103,12 +103,10 @@ def main(argv=None):
     bench = harness.bench("fig10")
     rps = {}
     knobs = {"chunk": args.chunk, "collective": args.collective,
-             "block_d": None, "use_pallas": False, "source": "explicit"}
+             "block_d": None, "use_pallas": False, "source": "explicit",
+             "platform": "cpu"}
     for d in args.devices:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                            + f" --xla_force_host_platform_device_count={d}")
-        env.setdefault("PYTHONPATH", "src")
+        env = harness.cpu_child_env(d)
         proc = subprocess.run(
             [sys.executable, "-m", "benchmarks.fig10_sharded", "--child",
              "--devices", str(d), "--nodes", str(args.nodes),

@@ -126,11 +126,7 @@ def _child_hlo(n: int, k: int, rounds: int, devices: int) -> None:
 def _sharded_hlo(n: int, k: int, rounds: int, devices: int):
     """Run :func:`_child_hlo` in a subprocess with the forced host
     device count; returns {engine: hlo_dict}."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + f" --xla_force_host_platform_device_count"
-                          f"={devices}")
-    env.setdefault("PYTHONPATH", "src")
+    env = harness.cpu_child_env(devices)
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks.fig12_sparse", "--child-hlo",
          "--nodes", str(n), "--k", str(k), "--rounds", str(rounds),

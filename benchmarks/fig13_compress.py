@@ -75,11 +75,7 @@ def _child_hlo(args, n: int, spec: str) -> None:
 
 
 def _sharded_hlo(args, n: int, spec: str):
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + f" --xla_force_host_platform_device_count"
-                          f"={args.hlo_devices}")
-    env.setdefault("PYTHONPATH", "src")
+    env = harness.cpu_child_env(args.hlo_devices)
     argv = ["--child-hlo", "--nodes", str(n), "--compress", spec]
     for flag, val in (("--dataset", args.dataset_name),
                       ("--rounds", args.rounds), ("--seed", args.seed),

@@ -177,6 +177,21 @@ def sweep_experiment_records(b: "Bench", prefix: str, spec, logs,
     return accs
 
 
+def cpu_child_env(devices: int) -> Dict[str, str]:
+    """Environment for a compile-only or simulated-device child process:
+    the CPU backend with ``devices`` host devices.  The parent may hold
+    the accelerator (a chip belongs to one process), so such a child
+    never asks for it; what it measures is CPU mechanics and HLO
+    counts."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + f" --xla_force_host_platform_device_count"
+                          f"={devices}")
+    env.setdefault("PYTHONPATH", "src")
+    return env
+
+
 def shape_dict(cfg, params) -> Dict:
     """The run's ``repro.tune`` shape key as a JSON-able dict."""
     import dataclasses

@@ -103,7 +103,7 @@ def main(argv=None):
         # Engine-path accuracy reproduction (GN-LeNet through the
         # compiled/sparse/sharded engines); smoke shrinks the CNN and
         # population but still exercises every engine row + the
-        # chunked-exchange bitwise pin.
+        # chunked-exchange pin.
         ("fig3_accuracy", lambda: fig3_accuracy.main(
             ["--nodes", "50", "100", "--rounds", "150",
              "--eval-every", "25"] if args.full
@@ -187,6 +187,11 @@ def main(argv=None):
                   f"{', '.join(repr(s) for s in unknown)}; "
                   f"valid sections: {', '.join(names)}", file=sys.stderr)
             return 2
+
+    from pathlib import Path
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache(Path(__file__).resolve().parents[1])
 
     failures = 0
     results = []

@@ -88,8 +88,9 @@ def tensordot_mix_leaf(w: jax.Array, leaf: jax.Array,
     and result buffers stay ``O(n · chunk_d)`` instead of ``O(n ·
     leaf_size)`` — the chunked-per-layer exchange path (DESIGN.md §12).
     Every output element is the *same* length-``n`` dot product either
-    way (the contraction axis is never split), so chunking is
-    bitwise-invariant.
+    way (the contraction axis is never split).  XLA's CPU backend picks
+    its dot kernel by operand shape, so chunked and whole-leaf results
+    can differ in the last ulp at larger ``n``/``D``.
 
     ``cast_back=False`` returns the f32 accumulation (the sharded psum
     schedule reduces partial products across devices before the final
@@ -120,8 +121,8 @@ def apply_mixing(w: jax.Array, stacked_params,
     Leaves have shape ``[n, ...]``.  The contraction runs in f32 and casts
     back to the leaf dtype, so bf16-stored models do not lose the averaging
     precision (matters once n is large).  ``chunk_d`` bounds the f32
-    upcast buffers per leaf (:func:`tensordot_mix_leaf`) — bitwise the
-    same result, only the buffer footprint changes; leave ``None`` when
+    upcast buffers per leaf (:func:`tensordot_mix_leaf`) — the same
+    result up to last-ulp dot-kernel rounding; leave ``None`` when
     leaves carry sharded trailing dims (chunking reshapes them).
     """
     prec = jax.lax.Precision(precision.lower()) \

@@ -100,7 +100,8 @@ def update_topology(state: MorphGraphState,
     # sim^(i,z) = mean_y sim(i,y) * sim(y,z) over shared informants y.
     inf_mask = (sim_valid[:, :, None] & sim_valid.T[None, :, :]
                 ).astype(jnp.float32)                    # [i, y, z]
-    est_num = jnp.einsum("iy,iyz,yz->iz", sim, inf_mask, sim)
+    est_num = jnp.einsum("iy,iyz,yz->iz", sim, inf_mask, sim,
+                         precision=jax.lax.Precision.HIGHEST)
     est_cnt = jnp.einsum("iyz->iz", inf_mask)
     est = est_num / jnp.maximum(est_cnt, 1.0)
     est_ok = est_cnt > 0

@@ -28,6 +28,9 @@ import numpy as np
 # The paper keeps the 5 most recent similarity reports per target peer.
 HISTORY_DEPTH = 5
 _EPS = 1e-12
+# Eq. 3 in f32 on every backend: a TPU's default matmul precision rounds
+# the operands to bf16, too coarse for cosines of near-equal models.
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 # ---------------------------------------------------------------------------
@@ -79,9 +82,11 @@ def pairwise_model_similarity(stacked_params) -> jax.Array:
         # contraction local per shard + one [n, n] all-reduce.
         lf = leaf.astype(jnp.float32)
         axes = tuple(range(1, lf.ndim))
-        dots = jnp.tensordot(lf, lf, axes=(axes, axes))      # [n, n]
+        dots = jnp.tensordot(lf, lf, axes=(axes, axes),
+                             precision=HIGHEST)                # [n, n]
         sq = jnp.einsum(lf, tuple(range(lf.ndim)),
-                        lf, tuple(range(lf.ndim)), (0,))     # [n]
+                        lf, tuple(range(lf.ndim)), (0,),
+                        precision=HIGHEST)                     # [n]
         norms = jnp.maximum(jnp.sqrt(sq), _EPS)
         acc = acc + dots / (norms[:, None] * norms[None, :])
     return acc / len(leaves)

@@ -85,7 +85,6 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..compress import (CompressConfig, decode_wire_tree,
@@ -267,11 +266,11 @@ class CompiledSuperstep:
       per step, so the f32-upcast / neighbor-gather buffers stay
       ``O(n · mix_chunk_d)`` instead of ``O(n · leaf_size)`` — the knob
       that lets multi-MB CNN layers through the engines.  Contraction
-      axes are never split: dense tensordot paths are bitwise-invariant
-      to the chunking; the sparse gather path is last-ulp allclose with
-      identical edge sequences (XLA fuses the self-term add
-      shape-dependently).  Pallas paths do their own blocking and
-      ignore it;
+      axes are never split: dense tensordot paths are invariant to the
+      chunking up to the last ulp of XLA's shape-chosen dot kernel;
+      the sparse gather path is last-ulp allclose with identical edge
+      sequences (XLA fuses the self-term add shape-dependently).
+      Pallas paths do their own blocking and ignore it;
     * ``eval_batch_chunk`` — evaluate the shared test set at most this
       many samples per vmapped forward pass, combining chunk means by
       sample-count weights (bounds the ``[n, b_test, ...]`` activation
@@ -1166,10 +1165,10 @@ class CompiledSuperstep:
             in_specs = (self._carry_specs, self._xs_specs[0], data_specs,
                         self._xs_specs[2], self._xs_specs[3],
                         self._xs_specs[4])
-        self._superstep = jax.jit(shard_map(
+        self._superstep = jax.jit(jax.shard_map(
             self._superstep_fn, mesh=self.mesh, in_specs=in_specs,
             out_specs=(self._carry_specs, self._ys_specs),
-            check_rep=False))
+            check_vma=False))
         return self._superstep
 
     def _prefetch_batches(self, k: int):

@@ -87,7 +87,8 @@ class RunnerConfig:
     # engine's f32-upcast / neighbor-gather buffers at
     # O(n · mix_chunk_d) — required headroom for multi-MB CNN params.
     # The node/slot contraction axis is never split: dense mixing is
-    # bitwise-invariant to this knob, the sparse gather path last-ulp
+    # invariant to this knob up to last-ulp dot-kernel rounding
+    # (bitwise at small shapes), the sparse gather path last-ulp
     # allclose (identical edges).  None = whole-leaf contractions.
     mix_chunk_d: Optional[int] = None
     # Evaluate the shared test set at most this many samples per vmapped
@@ -149,12 +150,23 @@ def make_evaluator(eval_fn: Callable,
         b = jax.tree_util.tree_leaves(test)[0].shape[0]
         if b <= batch_chunk:
             return per_node(test)
+        whole = b // batch_chunk
+        # lax.map runs the chunks one after another.  Unrolled, they are
+        # independent, and the TPU scheduler overlapped them: every
+        # chunk's activations were live at once, which is the peak this
+        # knob exists to bound.
+        mapped = jax.lax.map(per_node, jax.tree_util.tree_map(
+            lambda x: x[:whole * batch_chunk].reshape(
+                (whole, batch_chunk) + x.shape[1:]), test))
+        pieces = [(batch_chunk, jax.tree_util.tree_map(lambda x: x[i],
+                                                        mapped))
+                  for i in range(whole)]
+        if whole * batch_chunk < b:
+            pieces.append((b - whole * batch_chunk, per_node(
+                jax.tree_util.tree_map(lambda x: x[whole * batch_chunk:],
+                                       test))))
         losses, metrics = None, None
-        for s in range(0, b, batch_chunk):
-            size = min(batch_chunk, b - s)
-            piece = jax.tree_util.tree_map(
-                lambda x: x[s:s + batch_chunk], test)
-            pl, pm = per_node(piece)
+        for size, (pl, pm) in pieces:
             wl = pl * (size / b)
             wm = {k: v * (size / b) for k, v in pm.items()}
             losses = wl if losses is None else losses + wl
@@ -229,6 +241,7 @@ class DecentralizedRunner:
         self.net_stats = None              # dense-network counters ditto
         self.resolved_knobs = None         # set when the compiled engine
                                            # is built (repro.tune)
+        self.engine = None                 # the last compiled run's engine
         self._comm_bytes = 0
         self._model_bytes = cfg.model_bytes \
             or stacked_model_bytes(self.params, cfg.n_nodes)
@@ -330,12 +343,15 @@ class DecentralizedRunner:
         engine for in-graph-capable strategies (sharded when
         ``cfg.mesh_devices`` is set) and the per-round host loop
         otherwise; True/False force one path.  ``progress`` is invoked
-        with each evaluation's :class:`RoundRecord`."""
+        with each evaluation's :class:`RoundRecord`.  A compiled run
+        leaves its :class:`repro.dlrt.compiled.CompiledSuperstep` in
+        ``self.engine``, for throughput runs and HLO inspection."""
         use_compiled = self.cfg.compiled
         if use_compiled is None:
             use_compiled = getattr(self.strategy, "in_graph", False)
         if use_compiled:
             engine = self._make_engine()
+            self.engine = engine
             log = engine.run(progress)
             self.params, self.opt_state = engine.params, engine.opt_state
             self.edge_history = engine.edge_history

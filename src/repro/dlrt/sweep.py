@@ -55,7 +55,6 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core import apply_mixing
@@ -496,10 +495,10 @@ class SweepSuperstep:
             ys_spec = P(None, "exp")
             ys_specs = ys_spec if net is None \
                 else (ys_spec, ys_spec, ys_spec, ys_spec)
-            self._superstep = jax.jit(shard_map(
+            self._superstep = jax.jit(jax.shard_map(
                 superstep, mesh=mesh,
                 in_specs=(carry_specs, P(), data_specs, ex_specs),
-                out_specs=(carry_specs, ys_specs), check_rep=False))
+                out_specs=(carry_specs, ys_specs), check_vma=False))
             put = lambda spec: lambda x: jax.device_put(
                 x, NamedSharding(mesh, spec))
             self._params = jax.tree_util.tree_map(

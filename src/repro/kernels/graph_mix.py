@@ -23,6 +23,7 @@ def _mix_kernel(w_ref, x_ref, out_ref):
     out_ref[...] = jax.lax.dot_general(
         w_ref[...].astype(jnp.float32), x_ref[...].astype(jnp.float32),
         (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32).astype(out_ref.dtype)
 
 
@@ -60,6 +61,7 @@ def _masked_kernel(e_ref, x_ref, out_ref):
     out_ref[...] = jax.lax.dot_general(
         w, x_ref[...].astype(jnp.float32),
         (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32).astype(out_ref.dtype)
 
 

@@ -5,20 +5,26 @@ The dense ``graph_mix`` contracts a row-stochastic ``[n, n]`` W against
 are nonzero.  This kernel does the O(n·k·D) version straight from the
 CSR slots: **gather tiles, then MAC**.
 
+``X`` is viewed as ``[n, D / (s·128), s, 128]`` (``s`` = the sublane
+count of its dtype, 8 for f32 and 16 for bf16), so one ``(s, 128)``
+hardware tile holds ``s·128`` consecutive features of a single node.
 Per grid step ``(i, j)`` — receiver block i, D-block j — the kernel:
 
-1. reads the block's ``[block_n, k]`` neighbor indices from SMEM
-   (scalar memory, so the values can drive copies);
-2. DMAs the k neighbor rows' ``[block_d]`` tiles — plus each receiver's
-   own row for the diagonal term — from the HBM-resident ``X`` into a
-   VMEM scratch buffer (``X`` is never tiled through VMEM wholesale:
-   only the gathered rows move);
-3. reduces the weighted sum over the ``k + 1`` slots on the VPU in f32
-   and writes the ``[block_n, block_d]`` output tile.
+1. reads the block's ``[block_n, k + 1]`` sender indices and weights
+   from SMEM (scalar memory, so the values can drive copies); slot k is
+   each receiver's own row with weight ``w_self``;
+2. DMAs those rows' whole tiles of D-block j from the HBM-resident
+   ``X`` into a VMEM scratch buffer.  Each copy slices only the node
+   and tile axes, never inside a tile, which is what the chip's DMA
+   engine requires (``X`` is never tiled through VMEM wholesale: only
+   the gathered rows move);
+3. sums the weighted slots per receiver on the VPU in f32 and writes
+   the ``[block_n, block_d]`` output tile.
 
-Off-TPU the engine uses the XLA gather path
-(``repro.kernels.ops.mix_sparse`` falls back automatically);
-``interpret=True`` executes this body on CPU for the parity tests.
+On TPU it compiles to Mosaic; ``interpret=True`` executes this body on
+CPU for the parity tests.  There is no other fallback: the XLA sparse
+path is ``repro.sparse.mix``, which the engine selects with
+``use_pallas=False``.
 """
 from __future__ import annotations
 
@@ -29,29 +35,39 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+LANES = 128
 
-def _make_kernel(bn: int, k: int, bd: int):
-    def kernel(idx_ref, w_ref, ws_ref, x_hbm, o_ref, scratch, sem):
-        i = pl.program_id(0)
+
+def sublanes(dtype) -> int:
+    """Rows of one ``(sublanes, 128)`` TPU tile for ``dtype``."""
+    return 16 if jnp.dtype(dtype).itemsize == 2 else 8
+
+
+def _make_kernel(bn: int, slots: int, bt: int):
+    def kernel(idx_ref, w_ref, x_hbm, o_ref, scratch, sem):
         j = pl.program_id(1)
 
-        def load(s, carry):
-            r = s // (k + 1)
-            slot = s % (k + 1)
-            own = i * bn + r
-            neigh = idx_ref[r, jnp.minimum(slot, k - 1)]
-            row = jnp.where(slot == k, own, neigh)
-            cp = pltpu.make_async_copy(
-                x_hbm.at[row, pl.ds(j * bd, bd)], scratch.at[s], sem)
-            cp.start()
-            cp.wait()
+        def copy(s):
+            row = idx_ref[s // slots, s % slots]
+            return pltpu.make_async_copy(
+                x_hbm.at[row, pl.ds(j * bt, bt)], scratch.at[s], sem)
+
+        def start(s, carry):
+            copy(s).start()
             return carry
 
-        jax.lax.fori_loop(0, bn * (k + 1), load, 0)
-        data = scratch[...].reshape(bn, k + 1, bd).astype(jnp.float32)
-        wfull = jnp.concatenate([w_ref[...], ws_ref[...]], axis=1)
-        acc = (wfull[:, :, None] * data).sum(axis=1)
-        o_ref[...] = acc.astype(o_ref.dtype)
+        def wait(s, carry):
+            copy(s).wait()
+            return carry
+
+        jax.lax.fori_loop(0, bn * slots, start, 0)
+        jax.lax.fori_loop(0, bn * slots, wait, 0)
+        for r in range(bn):
+            acc = w_ref[r, 0] * scratch[r * slots].astype(jnp.float32)
+            for s in range(1, slots):
+                acc = acc + w_ref[r, s] * scratch[r * slots + s].astype(
+                    jnp.float32)
+            o_ref[r] = acc.astype(o_ref.dtype)
     return kernel
 
 
@@ -65,32 +81,44 @@ def graph_mix_sparse(idx: jax.Array, w: jax.Array, w_self: jax.Array,
     Shapes (pre-padded by ``ops.mix_sparse``): ``idx``/``w`` are
     ``[n, k]`` (int32 / f32, invalid slots = own row with weight 0),
     ``w_self`` is ``[n]`` f32, ``X`` is ``[n, D]`` with ``n`` a multiple
-    of ``block_n`` and ``D`` a multiple of ``block_d``.
+    of ``block_n``, ``D`` a multiple of ``block_d``, and ``block_d`` a
+    multiple of ``sublanes(X.dtype) · 128``.
     """
     n, k = idx.shape
     nx, d = x.shape
+    sl = sublanes(x.dtype)
     if n != nx:
         raise ValueError(f"idx rows ({n}) must match X rows ({nx})")
     if n % block_n != 0:
         raise ValueError(f"n={n} not a multiple of block_n={block_n}")
+    if block_d % (sl * LANES) != 0:
+        raise ValueError(f"block_d={block_d} not a multiple of the "
+                         f"{sl}x{LANES} tile")
     if d % block_d != 0:
         raise ValueError(f"D={d} not a multiple of block_d={block_d}")
-    return pl.pallas_call(
-        _make_kernel(block_n, k, block_d),
+    own = jnp.arange(n, dtype=jnp.int32)[:, None]
+    idx_all = jnp.concatenate([idx.astype(jnp.int32), own], axis=1)
+    w_all = jnp.concatenate([w.astype(jnp.float32),
+                             w_self.astype(jnp.float32)[:, None]], axis=1)
+    bt = block_d // (sl * LANES)
+    tiles = x.reshape(n, d // (sl * LANES), sl, LANES)
+    out = pl.pallas_call(
+        _make_kernel(block_n, k + 1, bt),
         grid=(n // block_n, d // block_d),
         in_specs=[
-            pl.BlockSpec((block_n, k), lambda i, j: (i, 0),
+            pl.BlockSpec((block_n, k + 1), lambda i, j: (i, 0),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec((block_n, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_n, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec((block_n, k + 1), lambda i, j: (i, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((block_n, block_d), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
+        out_specs=pl.BlockSpec((block_n, bt, sl, LANES),
+                               lambda i, j: (i, j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(tiles.shape, x.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_n * (k + 1), block_d), x.dtype),
+            pltpu.VMEM((block_n * (k + 1), bt, sl, LANES), x.dtype),
             pltpu.SemaphoreType.DMA,
         ],
         interpret=interpret,
-    )(idx.astype(jnp.int32), w.astype(jnp.float32),
-      w_self.astype(jnp.float32).reshape(n, 1), x)
+    )(idx_all, w_all, tiles)
+    return out.reshape(n, d)

@@ -1,8 +1,10 @@
 """jit'd wrappers around the Pallas kernels.
 
 Handle padding to block multiples, dtype policy, pytree flattening and
-the Eq.-3 layer averaging.  On CPU (this container) pass
-``interpret=True``; on TPU the same calls compile to Mosaic.
+the Eq.-3 layer averaging.  On TPU every call compiles to Mosaic; on any
+other backend pass ``interpret=True`` to execute the kernel bodies
+there (the CPU tests do).  Nothing here falls back to another
+implementation.
 
 Padding policy (the shapes the compiled superstep engine actually feeds):
 
@@ -23,14 +25,10 @@ import jax
 import jax.numpy as jnp
 
 from .graph_mix import (DEFAULT_BLOCK_D, graph_mix, graph_mix_masked)
-from .graph_mix_sparse import graph_mix_sparse
+from .graph_mix_sparse import LANES, graph_mix_sparse, sublanes
 from .pairwise_cosine import gram_matrix
 
 _EPS = 1e-12
-
-
-def _sublane(dtype) -> int:
-    return 16 if dtype in (jnp.bfloat16, jnp.float16) else 8
 
 
 def _pad_d(x: jax.Array, block_d: int) -> jax.Array:
@@ -73,7 +71,7 @@ def pairwise_cosine(x: jax.Array, *, block_d: Optional[int] = None,
     """Cosine similarity between all rows of ``X [n, D]`` -> [n, n]."""
     n = x.shape[0]
     bd = _pick_block(x.shape[-1], block_d)
-    xp = _pad_n(_pad_d(x, bd), _sublane(x.dtype))
+    xp = _pad_n(_pad_d(x, bd), sublanes(x.dtype))
     g = gram_matrix(xp, block_d=bd, interpret=interpret)[:n, :n]
     norms = jnp.maximum(jnp.sqrt(jnp.diag(g)), _EPS)
     return g / (norms[:, None] * norms[None, :])
@@ -108,7 +106,7 @@ def mix(w: jax.Array, x: jax.Array, *, block_d: Optional[int] = None,
     m = w.shape[0]
     n, d = x.shape
     bd = _pick_block(d, block_d)
-    sl = _sublane(x.dtype)
+    sl = sublanes(x.dtype)
     wp = jnp.pad(w, ((0, -m % sl), (0, -n % sl)))
     xp = _pad_n(_pad_d(x, bd), sl)
     y = graph_mix(wp, xp, block_d=bd, interpret=interpret)
@@ -122,21 +120,11 @@ def mix_masked(edges: jax.Array, x: jax.Array, *,
     """Fused uniform-average mixing from the raw in-edge matrix."""
     n, d = x.shape
     bd = _pick_block(d, block_d)
-    sl = _sublane(x.dtype)
+    sl = sublanes(x.dtype)
     ep = _pad_n(edges, sl, axes=(0, 1))
     xp = _pad_n(_pad_d(x, bd), sl)
     y = graph_mix_masked(ep, xp, block_d=bd, interpret=interpret)
     return y[:n, :d]
-
-
-def _mix_sparse_xla(idx, w, w_self, x):
-    """XLA gather + slot-sum fallback (same contraction the engine's
-    pure-jnp sparse path uses — ``repro.sparse.mix.sparse_mix_rows``)."""
-    xf = x.astype(jnp.float32)
-    acc = jnp.einsum("nk,nkd->nd", w.astype(jnp.float32), xf[idx],
-                     precision=jax.lax.Precision.HIGHEST)
-    acc = acc + w_self.astype(jnp.float32)[:, None] * xf
-    return acc.astype(x.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "block_n",
@@ -149,22 +137,28 @@ def mix_sparse(idx: jax.Array, w: jax.Array, w_self: jax.Array,
     """CSR k-sparse mix ``out[i] = w_self[i]·x[i] + Σ_s w[i,s]·x[idx[i,s]]``
     — O(n·k·D) instead of the dense ``mix``'s O(n²·D).
 
-    Routes to the block-sparse Pallas kernel
-    (:func:`repro.kernels.graph_mix_sparse.graph_mix_sparse`) on TPU, or
-    when ``interpret=True`` asks for its body on CPU; anywhere else it
-    falls back to the XLA gather path.  ``mask=None`` trusts ``idx``/``w``
-    to carry invalid slots as own-row/zero-weight already (the
-    :class:`repro.sparse.SparseAdjacency` invariant).
+    Runs the block-sparse Pallas kernel
+    (:func:`repro.kernels.graph_mix_sparse.graph_mix_sparse`): compiled
+    on TPU, or its body on CPU when ``interpret=True`` asks for it; on
+    any other backend it raises.  The XLA gather path is
+    :func:`repro.sparse.mix.sparse_mix_rows`.  ``block_d`` is rounded
+    up to whole ``(sublanes, 128)`` tiles of ``x.dtype``.  ``mask=None``
+    trusts ``idx``/``w`` to carry invalid slots as own-row/zero-weight
+    already (the :class:`repro.sparse.SparseAdjacency` invariant).
     """
     n, d = x.shape
+    if not interpret and jax.default_backend() != "tpu":
+        raise ValueError(
+            f"the sparse Pallas kernel compiles only for TPU (backend is "
+            f"{jax.default_backend()!r}); pass interpret=True to run its "
+            "body here, or mix through repro.sparse.mix")
     if mask is not None:
         rows = jnp.arange(n, dtype=jnp.int32)[:, None]
         idx = jnp.where(mask, idx, rows)
         w = jnp.where(mask, w, 0.0)
-    if not interpret and jax.default_backend() != "tpu":
-        return _mix_sparse_xla(idx, w, w_self, x)
-    bd = _pick_block(d, block_d)
-    bn = block_n or _sublane(x.dtype)
+    tile = sublanes(x.dtype) * LANES
+    bd = -(-_pick_block(d, block_d) // tile) * tile
+    bn = block_n or sublanes(x.dtype)
     pad = -n % bn
     if pad:
         tail = jnp.arange(n, n + pad, dtype=jnp.int32)

@@ -34,6 +34,7 @@ def _gram_kernel(x_ref, out_ref):
     x = x_ref[...].astype(jnp.float32)
     out_ref[...] += jax.lax.dot_general(
         x, x, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
 
 

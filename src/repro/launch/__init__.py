@@ -1,14 +1,16 @@
-"""Launchers: production mesh, multi-pod dry-run, training driver.
+"""Launchers: production mesh, multi-pod dry-run, training driver, and
+the persistent compile cache that entry points switch on.
 
-``dryrun`` must be imported first in its process (it sets XLA_FLAGS for
-512 placeholder devices); ``mesh``/``shapes`` are import-safe anywhere.
+Every module here is import-safe: ``dryrun`` claims its 512 placeholder
+CPU devices in its ``main``, not at import.
 """
+from .compile_cache import enable_compile_cache
 from .mesh import (CHIP_HBM_BYTES, HBM_BW, ICI_BW, PEAK_FLOPS_BF16,
                    make_production_mesh, mesh_chips)
 from .shapes import (SHAPES, TRAIN_MICROBATCH, ShapeSpec, cache_len,
                      input_specs, shape_config, skip_reason)
 
-__all__ = ["CHIP_HBM_BYTES", "HBM_BW", "ICI_BW", "PEAK_FLOPS_BF16",
-           "make_production_mesh", "mesh_chips", "SHAPES",
+__all__ = ["enable_compile_cache", "CHIP_HBM_BYTES", "HBM_BW", "ICI_BW",
+           "PEAK_FLOPS_BF16", "make_production_mesh", "mesh_chips", "SHAPES",
            "TRAIN_MICROBATCH", "ShapeSpec", "cache_len", "input_specs",
            "shape_config", "skip_reason"]

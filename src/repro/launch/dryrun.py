@@ -1,8 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=512 "
-    + os.environ.get("XLA_FLAGS", ""))
-
 """Multi-pod dry-run: lower + compile every (architecture x input shape)
 on the production meshes and extract the roofline terms.
 
@@ -14,9 +9,9 @@ Usage:
   python -m repro.launch.dryrun --arch llama3.2-3b --shape train_4k
   python -m repro.launch.dryrun --all --mesh both --out dryrun.json
 
-NOTE the XLA_FLAGS line above MUST run before any jax import (jax locks
-the device count on first init); only the dry-run uses 512 placeholder
-devices — tests/benches see the single real CPU device.
+``main`` puts this process on the CPU backend with 512 placeholder
+devices before JAX initialises a backend: the dry-run never claims an
+attached accelerator, and importing this module changes nothing.
 """
 import argparse
 import json
@@ -124,7 +119,7 @@ def build_lowered(arch: str, shape_name: str, multi_pod: bool):
             "multi_pod": multi_pod, "policy": cfg.sharding_policy,
             **meta}
 
-    with mesh:
+    with jax.set_mesh(mesh):
         if spec.kind == "train":
             opt = sgd(1e-2)        # paper-faithful plain SGD (Alg. 2 l.4)
             mb = S.TRAIN_MICROBATCH.get(arch)
@@ -259,6 +254,8 @@ def run_one(arch: str, shape_name: str, multi_pod: bool) -> Dict[str, Any]:
 
 
 def main(argv=None):
+    jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_num_cpu_devices", 512)
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="all",
                     help="architecture id or 'all'")

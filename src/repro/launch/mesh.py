@@ -6,6 +6,7 @@ touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 # TPU v5e hardware constants (per chip) used by the roofline analysis.
 PEAK_FLOPS_BF16 = 197e12        # FLOP/s
@@ -14,12 +15,20 @@ ICI_BW = 50e9                   # B/s per link
 CHIP_HBM_BYTES = 16 * 1024**3
 
 
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the engines and the
+    zoo place data with ``NamedSharding``/``shard_map`` and let the
+    partitioner propagate, which ``Explicit`` axes (JAX's default since
+    0.7) reject on reshapes of sharded arrays."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """Single pod: 256 chips as (data=16, model=16).
     Multi-pod: 2 pods = 512 chips as (pod=2, data=16, model=16)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def mesh_chips(mesh) -> int:
@@ -44,7 +53,7 @@ def make_superstep_mesh(num_devices: int | None = None):
                          "(set XLA_FLAGS=--xla_force_host_platform_"
                          "device_count=N before importing jax to simulate "
                          "more CPU devices)")
-    return jax.make_mesh((nd,), ("data",))
+    return _auto_mesh((nd,), ("data",))
 
 
 def make_sweep_mesh(exp_devices: int, node_devices: int = 1):
@@ -69,4 +78,4 @@ def make_sweep_mesh(exp_devices: int, node_devices: int = 1):
             f"{avail} local devices (set XLA_FLAGS=--xla_force_host_"
             "platform_device_count=N before importing jax to simulate "
             "more CPU devices)")
-    return jax.make_mesh((exp_devices, node_devices), ("exp", "data"))
+    return _auto_mesh((exp_devices, node_devices), ("exp", "data"))

@@ -79,7 +79,7 @@ def main(argv=None):
         mesh = make_production_mesh(multi_pod=(args.mesh == "multi"))
         state_shape = jax.eval_shape(lambda s: s, state)
         sh = train_state_sharding(mesh, cfg, state_shape)
-        with mesh:
+        with jax.set_mesh(mesh):
             state = jax.device_put(state, sh)
             step_topo = jax.jit(step_topo, in_shardings=(sh, None),
                                 out_shardings=(sh, None))

@@ -84,26 +84,11 @@ def _pick_chunk(s: int, target: int = Q_CHUNK, floor: int = 128) -> int:
 
 
 def _mesh_axis(name: str) -> int:
-    """Size of a mesh axis in the current jit mesh context (1 if absent)."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is not None and name in mesh.axis_names:
-            return mesh.shape[name]
-    except Exception:
-        pass
-    try:  # `with mesh:` context (how the dry-run/launcher trace)
-        import warnings
-        from jax.interpreters import pxla
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore")
-            mesh = pxla.thread_resources.env.physical_mesh
-        if not mesh.empty and name in mesh.axis_names:
-            return mesh.shape[name]
-    except Exception:
-        pass
-    return 1
-
-
+    """Size of a mesh axis in the current mesh context (1 if absent).
+    Callers that trace under a mesh enter it with ``jax.set_mesh``
+    (the dry-run and the launcher do)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh.shape[name] if name in mesh.axis_names else 1
 
 
 def _bh_sharding(x):

@@ -302,9 +302,10 @@ def test_dense_drop_fraction_matches_event_driven():
     # strategy (seed=0, n=6, k=2) fixes which 180 transfers happen over
     # 15 rounds.  Pin the exact counts so an RNG-keying change (stream
     # order, salt, hash) fails loudly instead of drifting inside the
-    # 3-sigma band below.
-    assert (engine.net_stats["dropped"], total) == (26, 180)
-    assert (stats.dropped, stats.sent) == (26, 180)
+    # 3-sigma band below.  The counts are those of JAX's partitionable
+    # threefry (the default since JAX 0.5; the legacy stream gave 26).
+    assert (engine.net_stats["dropped"], total) == (31, 180)
+    assert (stats.dropped, stats.sent) == (31, 180)
     sd = 3.0 * math.sqrt(rate * (1 - rate) / total)
     assert abs(dense_frac - rate) < sd
     assert abs(async_frac - rate) < sd

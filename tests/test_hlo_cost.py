@@ -75,7 +75,6 @@ def test_bytes_positive_and_bounded_below_by_io():
 
 def test_collectives_counted_with_trip_multiplier():
     """An all-reduce inside a scan counts once per iteration."""
-    import jax.experimental.shard_map as shmap
     from jax.sharding import Mesh, PartitionSpec as P
     devs = np.array(jax.devices()[:1]).reshape(1)
     mesh = Mesh(devs, ("x",))
@@ -87,7 +86,7 @@ def test_collectives_counted_with_trip_multiplier():
         y, _ = jax.lax.scan(body, x, None, length=5)
         return y
 
-    g = shmap.shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
+    g = jax.shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
     compiled = jax.jit(g).lower(
         jax.ShapeDtypeStruct((8, 16), jnp.float32)).compile()
     res = analyse_hlo(compiled.as_text())

@@ -140,15 +140,24 @@ def test_mix_sparse_mask_parks_invalid_slots():
 
 
 def test_mix_sparse_xla_fallback_matches_kernel():
-    """interpret=False on CPU routes to the XLA gather path — same
-    numbers as the Pallas body to f32 tolerance."""
+    """There is no silent fallback: interpret=False off TPU raises.  The
+    XLA gather path the engine selects with use_pallas=False
+    (repro.sparse.mix) gives the Pallas body's numbers to f32
+    tolerance."""
+    from repro.sparse import SparseAdjacency
+    from repro.sparse.mix import sparse_mix_rows
     n, d, k = 16, 512, 4
     x = jax.random.normal(jax.random.PRNGKey(2), (n, d))
     idx, w, w_self = _random_csr(7, n, k)
     kern = ops.mix_sparse(idx, w, w_self, x, interpret=True)
-    xla = ops.mix_sparse(idx, w, w_self, x, interpret=False)
+    adj = SparseAdjacency(idx=idx, w=w, w_self=w_self,
+                          mask=jnp.ones((n, k), bool))
+    xla = sparse_mix_rows(adj, x)
     np.testing.assert_allclose(np.asarray(kern), np.asarray(xla),
                                atol=1e-5)
+    if jax.default_backend() != "tpu":
+        with pytest.raises(ValueError, match="interpret=True"):
+            ops.mix_sparse(idx, w, w_self, x, interpret=False)
 
 
 def test_mix_sparse_pytree_matches_engine_gather_path():

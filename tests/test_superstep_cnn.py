@@ -291,8 +291,12 @@ def test_get_cnn_config_names_valid_datasets():
 @pytest.mark.slow
 def test_cnn_n50_chunk_and_stream_invariant():
     """n=50 Dirichlet(0.1) CNN through the dense engine: the chunked
-    memory-aware configuration is trajectory-identical to the plain
-    one at the paper's population scale."""
+    memory-aware configuration negotiates the same edges as the plain
+    one at the paper's population scale, with params equal to f32
+    rounding.  Not bitwise: XLA's CPU backend picks its dot kernel by
+    operand shape, so a [50, 50] x [50, 256] chunk can round the same
+    length-50 sums differently in the last ulp from the whole-leaf dot
+    (measured max |diff| 2.2e-8 after 6 rounds)."""
     n = 50
     # 10 classes like CIFAR-10: with fewer classes a Dirichlet(0.1)
     # node's total share rounds to zero too often to satisfy
@@ -318,9 +322,13 @@ def test_cnn_n50_chunk_and_stream_invariant():
     ref.run()
     ch = build(mix_chunk_d=256, eval_batch_chunk=64)
     ch.run()
+    assert len(ref.edge_history) == len(ch.edge_history)
     for ea, eb in zip(ref.edge_history, ch.edge_history):
         assert np.array_equal(ea, eb)
-    _assert_params_bitwise(ref, ch)
+    for x, y in zip(jax.tree_util.tree_leaves(ref.params),
+                    jax.tree_util.tree_leaves(ch.params)):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y),
+                                   rtol=0, atol=1e-6)
 
 
 # ---------------------------------------------------------------------------
