@@ -76,6 +76,21 @@ whole path reduces to the vanilla engine bit-for-bit (conformance:
 tests/test_dense_net.py).  Sharded mode gathers the snapshot ring
 along the node axis exactly like the parameters (``collective="gather"``
 only).
+
+**Stage scopes and host spans.**  Every round body, and the sweep
+engine's, names its work with ``jax.named_scope`` from one fixed set,
+:data:`STAGES`: ``draw`` (in-scan batch drawing), ``local_step`` (the
+vmapped SGD step and its per-node selection), ``codec``, ``similarity``,
+``topology`` (``graph_round`` and CSR conversion), ``mix`` (every mixing
+schedule, its collectives and the consensus correction) and ``net`` (the
+dense network model, its staleness contraction included).  The names
+land in each HLO instruction's ``op_name`` metadata only, so profiler
+viewers group device time by stage while the arithmetic stays the same.
+On the host, :meth:`CompiledSuperstep.run` marks its loop with three
+``jax.profiler.TraceAnnotation`` spans on the device trace's clock:
+``dlrt.dispatch`` (building a superstep's or evaluation's inputs and
+launching it), ``dlrt.readback`` (fetching and decoding its results) and
+``dlrt.progress`` (the caller's callback).
 """
 from __future__ import annotations
 
@@ -110,6 +125,9 @@ SPARSE_MIX_MODES = ("exact", "gather")
 # Above this population the sparse engine stops decoding dense [n, n]
 # edge matrices into edge_history and appends compact (idx, mask) pairs.
 SPARSE_EDGE_DECODE_MAX = 4096
+# The named scopes of a round's stages (module docstring).
+STAGES = ("draw", "local_step", "codec", "similarity", "topology", "mix",
+          "net")
 
 
 def eval_boundaries(rounds: int, eval_every: int) -> List[Tuple[int, int]]:
@@ -147,6 +165,7 @@ def _pad_nodes(tree, n_pad: int):
 # arrives explicitly.
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("local_step")
 def net_select(mask, new, old):
     """Per-node where over a state pytree; scalar leaves (shared
     optimizer counters) and leaves not on the node axis always
@@ -159,6 +178,7 @@ def net_select(mask, new, old):
     return jax.tree_util.tree_map(one, new, old)
 
 
+@jax.named_scope("net")
 def net_effective(edges, w, up, step, stal, drop, S: int, *,
                   uniform: bool):
     """Delivery + mixing plan at logical n: which negotiated edges
@@ -188,6 +208,7 @@ def net_effective(edges, w, up, step, stal, drop, S: int, *,
     return delivered, d_idx, w_stal, stale_counts
 
 
+@jax.named_scope("net")
 def net_push(params, netstate, rnd, step, S: int):
     """Advance both rings: slot 0 becomes this round's post-step
     snapshot / last-step round."""
@@ -203,6 +224,7 @@ def net_push(params, netstate, rnd, step, S: int):
     return hist, lhist
 
 
+@jax.named_scope("net")
 def net_observed(rnd, lhist, d_idx, delivered):
     """Sum over delivered edges of the *content* staleness: this
     round minus the sender's last completed step as of the
@@ -604,6 +626,7 @@ class CompiledSuperstep:
         # path (sparse_mix="gather" parity), not the scaling path.
         compat_k = max(1, n - 1)
 
+        @jax.named_scope("similarity")
         def refresh_sim(rnd, params_logical, sim):
             return jax.lax.cond(
                 rnd % cfg.sim_every == 0,
@@ -654,6 +677,7 @@ class CompiledSuperstep:
         S = self._net_S
         model_bytes = self._wire_bytes
 
+        @jax.named_scope("net")
         def net_masks(rnd):
             r = jnp.minimum(rnd, cfg.rounds - 1)
             up, step = self._net_up[r], self._net_step[r]      # [n] bool
@@ -661,6 +685,7 @@ class CompiledSuperstep:
             drop = net.drop_mask(rnd, n)
             return up, step, stal, drop
 
+        @jax.named_scope("net")
         def net_mix(w_stal_flat, hist):
             """``[m, n_h * S] @ [n_h * S, ...]`` — the staleness-expanded
             contraction, same f32/HIGHEST schedule as ``apply_mixing`` so
@@ -693,48 +718,52 @@ class CompiledSuperstep:
                 # the advanced replica hat + decode(wire), never the raw
                 # params.  In net mode the ring's slot 0 (last round's
                 # push) is the replica the delta is coded against.
-                hat_prev = hat if net is None else \
-                    jax.tree_util.tree_map(lambda x: x[:, 0], netstate[0])
-                wire, decoded, resid = comp(params, hat_prev, resid)
+                with jax.named_scope("codec"):
+                    hat_prev = hat if net is None else \
+                        jax.tree_util.tree_map(lambda x: x[:, 0],
+                                               netstate[0])
+                    wire, decoded, resid = comp(params, hat_prev, resid)
                 if net is None:
                     hat = decoded
             if sim_fn is not None:
                 sim_src = decoded if codec is not None and codec.sim \
                     else params
                 sim = refresh_sim(rnd, sim_src, sim)
-            gstate, edges, w = strategy.graph_round(gstate, rnd, sim)
-            if net is None:
-                if codec is not None:
-                    if engine == "sparse" and sparse_mix == "gather":
-                        adj = dense_to_csr(edges, w.astype(jnp.float32),
-                                           compat_k)
-                        params = apply_consensus_correction(
-                            _sparse_mix(adj, decoded), params, decoded,
-                            gamma=gam)
-                    else:
-                        params = apply_mixing_compressed(
-                            w.astype(jnp.float32), params, decoded,
-                            chunk_d=mix_chunk_d, gamma=gam)
-                elif engine == "sparse" and sparse_mix == "gather":
+            with jax.named_scope("topology"):
+                gstate, edges, w = strategy.graph_round(gstate, rnd, sim)
+                if net is None and engine == "sparse" \
+                        and sparse_mix == "gather":
                     # Compat numerics path: convert the dense round
                     # output to CSR in-scan and mix through the sparse
                     # gather contraction (parity-tested vs the dense
-                    # engine to tolerance; "exact" mode below is the
-                    # bitwise path).
+                    # engine to tolerance; "exact" mode is the bitwise
+                    # path).
                     adj = dense_to_csr(edges, w.astype(jnp.float32),
                                        compat_k)
-                    params = _sparse_mix(adj, params)
-                elif use_pallas and uniform:
-                    params = ops.mix_masked_pytree(edges, params,
-                                                   block_d=block_d,
-                                                   interpret=interpret)
-                elif use_pallas:
-                    params = ops.mix_pytree(w.astype(jnp.float32), params,
-                                            block_d=block_d,
-                                            interpret=interpret)
-                else:
-                    params = apply_mixing(w.astype(jnp.float32), params,
-                                          chunk_d=mix_chunk_d)
+            if net is None:
+                with jax.named_scope("mix"):
+                    if codec is not None:
+                        if engine == "sparse" and sparse_mix == "gather":
+                            params = apply_consensus_correction(
+                                _sparse_mix(adj, decoded), params, decoded,
+                                gamma=gam)
+                        else:
+                            params = apply_mixing_compressed(
+                                w.astype(jnp.float32), params, decoded,
+                                chunk_d=mix_chunk_d, gamma=gam)
+                    elif engine == "sparse" and sparse_mix == "gather":
+                        params = _sparse_mix(adj, params)
+                    elif use_pallas and uniform:
+                        params = ops.mix_masked_pytree(edges, params,
+                                                       block_d=block_d,
+                                                       interpret=interpret)
+                    elif use_pallas:
+                        params = ops.mix_pytree(w.astype(jnp.float32),
+                                                params, block_d=block_d,
+                                                interpret=interpret)
+                    else:
+                        params = apply_mixing(w.astype(jnp.float32), params,
+                                              chunk_d=mix_chunk_d)
                 return (params, opt_state, gstate, sim, netstate,
                         resid, hat), edges
             netstate = net_push(decoded if codec is not None else params,
@@ -750,8 +779,9 @@ class CompiledSuperstep:
                 # the consensus-difference correction against this
                 # round's own replica (slot 0 after the push).
                 mixed = net_mix(w_stal.reshape(n, n * S), netstate[0])
-                params = apply_consensus_correction(mixed, params,
-                                                    decoded, gamma=gam)
+                with jax.named_scope("mix"):
+                    params = apply_consensus_correction(mixed, params,
+                                                        decoded, gamma=gam)
             return (params, opt_state, gstate, sim, netstate, resid,
                     hat), (edges, delivered, stale_counts, obs_sum)
 
@@ -787,34 +817,41 @@ class CompiledSuperstep:
             rnd, batch = xs
             new_p, new_o = local_step(params, opt_state, batch)
             up, step, stal, drop = net_masks(rnd)
-            step_local = jax.lax.dynamic_slice_in_dim(
-                pad_mask(step), shard_index() * n_local, n_local, 0)
+            with jax.named_scope("net"):
+                step_local = jax.lax.dynamic_slice_in_dim(
+                    pad_mask(step), shard_index() * n_local, n_local, 0)
             params = net_select(step_local, new_p, params)
             opt_state = net_select(step_local, new_o, opt_state)
             if codec is not None:
                 # Local rows' replica = ring slot 0 before the push.
-                hat_prev = jax.tree_util.tree_map(lambda x: x[:, 0],
-                                                  netstate[0])
-                wire, decoded, resid = comp(params, hat_prev, resid)
+                with jax.named_scope("codec"):
+                    hat_prev = jax.tree_util.tree_map(lambda x: x[:, 0],
+                                                      netstate[0])
+                    wire, decoded, resid = comp(params, hat_prev, resid)
             netstate = net_push(decoded if codec is not None else params,
                                 netstate, rnd, step, S)
-            hist_full = gather_full(netstate[0])
+            with jax.named_scope("net"):
+                hist_full = gather_full(netstate[0])
             if sim_fn is not None:
                 logical = jax.tree_util.tree_map(lambda x: x[:n, 0],
                                                  hist_full)
                 sim = refresh_sim(rnd, logical, sim)
-            gstate, edges, w = strategy.graph_round(gstate, rnd, sim)
+            with jax.named_scope("topology"):
+                gstate, edges, w = strategy.graph_round(gstate, rnd, sim)
             delivered, d_idx, w_stal, stale_counts = net_effective(
                 edges, w, up, step, stal, drop, S, uniform=uniform)
             obs_sum = net_observed(rnd, netstate[1], d_idx, delivered)
-            w_rows = jax.lax.dynamic_slice_in_dim(
-                embed_w_stal(w_stal), shard_index() * n_local, n_local, 0)
+            with jax.named_scope("net"):
+                w_rows = jax.lax.dynamic_slice_in_dim(
+                    embed_w_stal(w_stal), shard_index() * n_local,
+                    n_local, 0)
             if codec is None:
                 params = net_mix(w_rows, hist_full)
             else:
                 mixed = net_mix(w_rows, hist_full)
-                params = apply_consensus_correction(mixed, params,
-                                                    decoded, gamma=gam)
+                with jax.named_scope("mix"):
+                    params = apply_consensus_correction(mixed, params,
+                                                        decoded, gamma=gam)
             return (params, opt_state, gstate, sim, netstate, resid,
                     hat), (edges, delivered, stale_counts, obs_sum)
 
@@ -831,27 +868,30 @@ class CompiledSuperstep:
             params, opt_state = local_step(params, opt_state, batch)
             full = decoded_full = None
             if codec is not None:
-                if collective == "gather":
-                    # hat is carried replicated at full n_pad: encode
-                    # the own rows' delta against its matching slice,
-                    # gather the wire, and rebuild the whole decoded
-                    # population as hat + decode(gathered deltas) —
-                    # which is the next round's hat.  Row-wise codec
-                    # ops, so the gathered decode is bitwise the
-                    # senders' local decode of the same rows.
-                    off = shard_index() * n_local
-                    wire, decoded, resid = comp(
-                        params, slice_rows(hat, off), resid)
-                    decoded_full = jax.tree_util.tree_map(
-                        jnp.add, hat, decode_rows(gather_full(wire),
-                                                  params))
-                    hat = decoded_full
-                else:
-                    # psum mode only ever needs the local rows' replica.
-                    wire, decoded, resid = comp(params, hat, resid)
-                    hat = decoded
+                with jax.named_scope("codec"):
+                    if collective == "gather":
+                        # hat is carried replicated at full n_pad: encode
+                        # the own rows' delta against its matching slice,
+                        # gather the wire, and rebuild the whole decoded
+                        # population as hat + decode(gathered deltas) —
+                        # which is the next round's hat.  Row-wise codec
+                        # ops, so the gathered decode is bitwise the
+                        # senders' local decode of the same rows.
+                        off = shard_index() * n_local
+                        wire, decoded, resid = comp(
+                            params, slice_rows(hat, off), resid)
+                        decoded_full = jax.tree_util.tree_map(
+                            jnp.add, hat, decode_rows(gather_full(wire),
+                                                      params))
+                        hat = decoded_full
+                    else:
+                        # psum mode only ever needs the local rows'
+                        # replica.
+                        wire, decoded, resid = comp(params, hat, resid)
+                        hat = decoded
             elif collective == "gather":
-                full = gather_full(params)
+                with jax.named_scope("mix"):
+                    full = gather_full(params)
             if sim_fn is not None and collective == "gather":
                 src = decoded_full if codec is not None else full
                 logical = jax.tree_util.tree_map(lambda x: x[:n], src)
@@ -876,35 +916,39 @@ class CompiledSuperstep:
                             lambda x: jax.lax.all_gather(
                                 x, axes, axis=0, tiled=True)[:n], p)
                     return sim_fn(logical).astype(jnp.float32)
-                sim = jax.lax.cond(rnd % cfg.sim_every == 0,
-                                   psum_mode_refresh,
-                                   lambda p, s: s, params, sim)
-            gstate, edges, w = strategy.graph_round(gstate, rnd, sim)
-            w_pad = embed_w(w.astype(jnp.float32))
-            if collective == "gather":
-                w_rows = jax.lax.dynamic_slice_in_dim(
-                    w_pad, shard_index() * n_local, n_local, 0)
-                if codec is None:
-                    params = mix_rows(w_rows, full)
+                with jax.named_scope("similarity"):
+                    sim = jax.lax.cond(rnd % cfg.sim_every == 0,
+                                       psum_mode_refresh,
+                                       lambda p, s: s, params, sim)
+            with jax.named_scope("topology"):
+                gstate, edges, w = strategy.graph_round(gstate, rnd, sim)
+            with jax.named_scope("mix"):
+                w_pad = embed_w(w.astype(jnp.float32))
+                if collective == "gather":
+                    w_rows = jax.lax.dynamic_slice_in_dim(
+                        w_pad, shard_index() * n_local, n_local, 0)
+                    if codec is None:
+                        params = mix_rows(w_rows, full)
+                    else:
+                        mixed = mix_rows(w_rows, decoded_full)
+                        params = apply_consensus_correction(
+                            mixed, params, decoded, gamma=gam)
                 else:
-                    mixed = mix_rows(w_rows, decoded_full)
-                    params = apply_consensus_correction(mixed, params,
-                                                        decoded, gamma=gam)
-            else:
-                w_cols = jax.lax.dynamic_slice_in_dim(
-                    w_pad, shard_index() * n_local, n_local, 1)
-                if codec is None:
-                    params = mix_psum(w_cols, params)
-                else:
-                    # Contributions (including the self partial) come
-                    # from the decoded payload; the consensus correction
-                    # restores the exact local model after the reduce.
-                    # The collective itself still moves f32 partials —
-                    # compression shrinks the psum schedule's memory, not
-                    # its collective bytes (documented in DESIGN.md §13).
-                    mixed = mix_psum(w_cols, decoded)
-                    params = apply_consensus_correction(mixed, params,
-                                                        decoded, gamma=gam)
+                    w_cols = jax.lax.dynamic_slice_in_dim(
+                        w_pad, shard_index() * n_local, n_local, 1)
+                    if codec is None:
+                        params = mix_psum(w_cols, params)
+                    else:
+                        # Contributions (including the self partial) come
+                        # from the decoded payload; the consensus
+                        # correction restores the exact local model after
+                        # the reduce.  The collective itself still moves
+                        # f32 partials — compression shrinks the psum
+                        # schedule's memory, not its collective bytes
+                        # (documented in DESIGN.md §13).
+                        mixed = mix_psum(w_cols, decoded)
+                        params = apply_consensus_correction(
+                            mixed, params, decoded, gamma=gam)
             return (params, opt_state, gstate, sim, netstate, resid,
                     hat), edges
 
@@ -916,18 +960,22 @@ class CompiledSuperstep:
             rnd, batch = xs
             params, opt_state = local_step(params, opt_state, batch)
             if codec is not None:
-                wire, decoded, resid = comp(params, hat, resid)
+                with jax.named_scope("codec"):
+                    wire, decoded, resid = comp(params, hat, resid)
                 hat = decoded
                 ctrl_src = decoded if codec.sim else params
             else:
                 ctrl_src = params
-            gstate, adj = strategy.graph_round(
-                gstate, rnd, ctrl_src if needs_params else None)
-            if codec is None:
-                params = _sparse_mix(adj, params)
-            else:
-                params = apply_consensus_correction(
-                    _sparse_mix(adj, decoded), params, decoded, gamma=gam)
+            with jax.named_scope("topology"):
+                gstate, adj = strategy.graph_round(
+                    gstate, rnd, ctrl_src if needs_params else None)
+            with jax.named_scope("mix"):
+                if codec is None:
+                    params = _sparse_mix(adj, params)
+                else:
+                    params = apply_consensus_correction(
+                        _sparse_mix(adj, decoded), params, decoded,
+                        gamma=gam)
             return (params, opt_state, gstate, sim, netstate, resid,
                     hat), (adj.idx, adj.mask)
 
@@ -981,17 +1029,20 @@ class CompiledSuperstep:
             params, opt_state = local_step(params, opt_state, batch)
             off = shard_index() * n_local
             if codec is not None:
-                hat_own = slice_rows(hat, off) \
-                    if collective == "gather" else hat
-                wire, decoded, resid = comp(params, hat_own, resid)
+                with jax.named_scope("codec"):
+                    hat_own = slice_rows(hat, off) \
+                        if collective == "gather" else hat
+                    wire, decoded, resid = comp(params, hat_own, resid)
             full = full_dec = None
             if collective == "gather":
                 if codec is None:
-                    full = gather_full(params)
+                    with jax.named_scope("mix"):
+                        full = gather_full(params)
                 else:
-                    full_dec = jax.tree_util.tree_map(
-                        jnp.add, hat, decode_rows(gather_full(wire),
-                                                  params))
+                    with jax.named_scope("codec"):
+                        full_dec = jax.tree_util.tree_map(
+                            jnp.add, hat, decode_rows(gather_full(wire),
+                                                      params))
                     hat = full_dec
             elif codec is not None:
                 hat = decoded
@@ -1022,28 +1073,31 @@ class CompiledSuperstep:
                                             jnp.float32 if codec is not None
                                             else x.dtype),
                         p)
-                ctrl = jax.lax.cond(rnd % ctrl_every == 0, ctrl_gather,
-                                    ctrl_hold, params)
-            gstate, adj = strategy.graph_round(gstate, rnd, ctrl)
-            apad = pad_adjacency(adj, n_pad)
-            if collective == "gather":
-                sl = lambda a: jax.lax.dynamic_slice_in_dim(
-                    a, off, n_local, 0)
-                adj_l = SparseAdjacency(sl(apad.idx), sl(apad.w),
-                                        sl(apad.w_self), sl(apad.mask))
-                rows = off + jnp.arange(n_local, dtype=jnp.int32)
-                if codec is None:
-                    params = _sparse_mix(adj_l, full, rows=rows)
+                with jax.named_scope("topology"):
+                    ctrl = jax.lax.cond(rnd % ctrl_every == 0,
+                                        ctrl_gather, ctrl_hold, params)
+            with jax.named_scope("topology"):
+                gstate, adj = strategy.graph_round(gstate, rnd, ctrl)
+                apad = pad_adjacency(adj, n_pad)
+            with jax.named_scope("mix"):
+                if collective == "gather":
+                    sl = lambda a: jax.lax.dynamic_slice_in_dim(
+                        a, off, n_local, 0)
+                    adj_l = SparseAdjacency(sl(apad.idx), sl(apad.w),
+                                            sl(apad.w_self), sl(apad.mask))
+                    rows = off + jnp.arange(n_local, dtype=jnp.int32)
+                    if codec is None:
+                        params = _sparse_mix(adj_l, full, rows=rows)
+                    else:
+                        params = apply_consensus_correction(
+                            _sparse_mix(adj_l, full_dec, rows=rows),
+                            params, decoded, gamma=gam)
+                elif codec is None:
+                    params = sparse_mix_psum(apad, params, off)
                 else:
                     params = apply_consensus_correction(
-                        _sparse_mix(adj_l, full_dec, rows=rows),
-                        params, decoded, gamma=gam)
-            elif codec is None:
-                params = sparse_mix_psum(apad, params, off)
-            else:
-                params = apply_consensus_correction(
-                    sparse_mix_psum(apad, decoded, off), params, decoded,
-                    gamma=gam)
+                        sparse_mix_psum(apad, decoded, off), params,
+                        decoded, gamma=gam)
             return (params, opt_state, gstate, sim, netstate, resid,
                     hat), (adj.idx, adj.mask)
 
@@ -1059,7 +1113,8 @@ class CompiledSuperstep:
         else:
             def superstep(carry, rnds, data, index, sizes, ids):
                 def step(c, rnd):
-                    batch = stream.draw(data, index, sizes, ids, rnd)
+                    with jax.named_scope("draw"):
+                        batch = stream.draw(data, index, sizes, ids, rnd)
                     return body(c, (rnd, batch))
                 return jax.lax.scan(step, carry, rnds)
 
@@ -1215,6 +1270,15 @@ class CompiledSuperstep:
         """Execute rounds ``[start, end]`` as one on-device superstep and
         decode the stacked per-round edge matrices (``[K, n, n]`` bool,
         logical n)."""
+        with jax.profiler.TraceAnnotation("dlrt.dispatch"):
+            ys = self._dispatch(start, end)
+        with jax.profiler.TraceAnnotation("dlrt.readback"):
+            return self._decode(ys)
+
+    def _dispatch(self, start: int, end: int):
+        """Launch rounds ``[start, end]`` as one superstep; the new carry
+        replaces the engine's state and the stacked per-round outputs
+        are returned (still on the device)."""
         k = end - start + 1
         rnds = jnp.arange(start, end + 1)
         carry = (self._params, self._opt_state, self.gstate, self.sim,
@@ -1228,6 +1292,12 @@ class CompiledSuperstep:
             carry, ys = fn(carry, rnds, *self._stream_args)
         (self._params, self._opt_state, self.gstate, self.sim,
          self._netstate, self._resid, self._hat) = carry
+        return ys
+
+    def _decode(self, ys) -> np.ndarray:
+        """Fetch a superstep's per-round outputs to the host and decode
+        them into ``edge_history``, comm bytes and the network counters;
+        returns the ``[K, n, n]`` edge stack."""
         if hasattr(self.strategy, "set_graph_state"):
             self.strategy.set_graph_state(self.gstate, self.sim)
         if self.sparse_native:
@@ -1281,10 +1351,12 @@ class CompiledSuperstep:
         """Evaluate every node on the shared test set after round ``rnd``
         and append the §IV-A4 :class:`RoundRecord` (mean accuracy/loss,
         inter-node variance, cumulative comm bytes, isolation count)."""
-        losses, metrics = self._evaluate(self.params, self.test_batch)
-        rec = make_round_record(rnd, losses, metrics, self._comm_bytes,
-                                edges, isolated=self._last_isolated)
-        self.log.add(rec)
+        with jax.profiler.TraceAnnotation("dlrt.dispatch"):
+            losses, metrics = self._evaluate(self.params, self.test_batch)
+        with jax.profiler.TraceAnnotation("dlrt.readback"):
+            rec = make_round_record(rnd, losses, metrics, self._comm_bytes,
+                                    edges, isolated=self._last_isolated)
+            self.log.add(rec)
         return rec
 
     def run(self, progress: Optional[Callable[[RoundRecord], None]] = None
@@ -1307,7 +1379,8 @@ class CompiledSuperstep:
                 s = e + 1
             rec = self.evaluate(end, edges_np[-1])
             if progress is not None:
-                progress(rec)
+                with jax.profiler.TraceAnnotation("dlrt.progress"):
+                    progress(rec)
         return self.log
 
     def run_steps(self, rounds: int, chunk: Optional[int] = None) -> None:

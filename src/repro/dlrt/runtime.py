@@ -119,6 +119,7 @@ class RunnerConfig:
 def make_local_step(loss_fn: Callable, optimizer: Optimizer) -> Callable:
     """Vmapped per-node SGD step — the same traced function whether it
     runs per round (host loop) or inside the superstep scan."""
+    @jax.named_scope("local_step")
     def local_step(params, opt_state, batch):
         def one(p, s, b):
             grads = jax.grad(lambda q: loss_fn(q, b)[0])(p)

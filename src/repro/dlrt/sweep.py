@@ -362,6 +362,7 @@ class SweepSuperstep:
             tail = jnp.arange(n, n_pad)
             return wp.at[tail, tail].set(1)
 
+        @jax.named_scope("topology")
         def graph_round(gstate_e, rnd, sim_e, ex_e):
             if hp_axis:
                 return first.sweep_graph_round(
@@ -369,6 +370,7 @@ class SweepSuperstep:
                     delta_r=ex_e.get("delta_r"), beta=ex_e.get("beta"))
             return first.graph_round(gstate_e, rnd, sim_e)
 
+        @jax.named_scope("similarity")
         def refresh_sim(rnd, params_logical, sim_e):
             # Unbatched predicate: under vmap this stays a real cond —
             # off-cadence rounds skip the Eq.-3 kernel entirely.
@@ -377,6 +379,7 @@ class SweepSuperstep:
                 lambda p, s: sim_fn(p).astype(jnp.float32),
                 lambda p, s: s, params_logical, sim_e)
 
+        @jax.named_scope("net")
         def net_arrays(rnd, ex_e):
             # The single engine's net_masks, rebuilt from this
             # experiment's folded profile scalars: same clip / diag /
@@ -393,6 +396,7 @@ class SweepSuperstep:
                                                ex_e["drop"])
             return up, step, stal, drop
 
+        @jax.named_scope("net")
         def net_mix(w_stal_flat, hist):
             flat = jax.tree_util.tree_map(
                 lambda l: l.reshape((l.shape[0] * l.shape[1],)
@@ -406,9 +410,10 @@ class SweepSuperstep:
             # round_body of dlrt.compiled with the per-experiment
             # operands threaded through `ex_e`.
             params, opt_state, gstate_e, sim_e, netstate = carry_e
-            batch = stream0.draw(data, ex_e["index"], ex_e["sizes"],
-                                 jnp.arange(n, dtype=jnp.int32), rnd,
-                                 seed=ex_e["data_seed"])
+            with jax.named_scope("draw"):
+                batch = stream0.draw(data, ex_e["index"], ex_e["sizes"],
+                                     jnp.arange(n, dtype=jnp.int32), rnd,
+                                     seed=ex_e["data_seed"])
             new_p, new_o = local_step(params, opt_state, batch)
             if net is None:
                 params, opt_state = new_p, new_o
@@ -420,8 +425,9 @@ class SweepSuperstep:
                 sim_e = refresh_sim(rnd, params, sim_e)
             gstate_e, edges, w = graph_round(gstate_e, rnd, sim_e, ex_e)
             if net is None:
-                params = apply_mixing(w.astype(jnp.float32), params,
-                                      chunk_d=mix_chunk_d)
+                with jax.named_scope("mix"):
+                    params = apply_mixing(w.astype(jnp.float32), params,
+                                          chunk_d=mix_chunk_d)
                 return (params, opt_state, gstate_e, sim_e, netstate), edges
             netstate = net_push(params, netstate, rnd, step, S)
             delivered, d_idx, w_stal, stale_counts = net_effective(
@@ -436,22 +442,26 @@ class SweepSuperstep:
             # "data" — the gather schedule of round_body_sharded, per
             # experiment (no-net only).
             params, opt_state, gstate_e, sim_e, netstate = carry_e
-            ids = shard_index() * n_local \
-                + jnp.arange(n_local, dtype=jnp.int32)
-            batch = stream0.draw(data, ex_e["index"], ex_e["sizes"], ids,
-                                 rnd, seed=ex_e["data_seed"])
+            with jax.named_scope("draw"):
+                ids = shard_index() * n_local \
+                    + jnp.arange(n_local, dtype=jnp.int32)
+                batch = stream0.draw(data, ex_e["index"], ex_e["sizes"],
+                                     ids, rnd, seed=ex_e["data_seed"])
             params, opt_state = local_step(params, opt_state, batch)
-            full = gather_full(params)
+            with jax.named_scope("mix"):
+                full = gather_full(params)
             if sim_fn is not None:
                 logical = jax.tree_util.tree_map(lambda x: x[:n], full)
                 sim_e = refresh_sim(rnd, logical, sim_e)
             gstate_e, edges, w = graph_round(gstate_e, rnd, sim_e, ex_e)
-            w_rows = jax.lax.dynamic_slice_in_dim(
-                embed_w(w.astype(jnp.float32)), shard_index() * n_local,
-                n_local, 0)
-            params = jax.tree_util.tree_map(
-                lambda leaf: tensordot_mix_leaf(w_rows, leaf, mix_chunk_d),
-                full)
+            with jax.named_scope("mix"):
+                w_rows = jax.lax.dynamic_slice_in_dim(
+                    embed_w(w.astype(jnp.float32)), shard_index() * n_local,
+                    n_local, 0)
+                params = jax.tree_util.tree_map(
+                    lambda leaf: tensordot_mix_leaf(w_rows, leaf,
+                                                    mix_chunk_d),
+                    full)
             return (params, opt_state, gstate_e, sim_e, netstate), edges
 
         body = exp_round_node_sharded if node_shard > 1 else exp_round
