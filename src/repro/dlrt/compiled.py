@@ -1244,7 +1244,8 @@ class CompiledSuperstep:
     def compiled_hlo(self, chunk: Optional[int] = None,
                      start: int = 0) -> str:
         """Compile — without executing — one ``chunk``-round superstep
-        and return its post-optimization HLO text.
+        and return its post-optimization HLO text (:meth:`lower`, then
+        XLA's passes).
 
         This is the autotuner's stage-1 surface: candidates are lowered
         and costed with :func:`repro.launch.hlo_cost.analyse_hlo` (the
@@ -1253,18 +1254,21 @@ class CompiledSuperstep:
         mode this draws ``chunk`` batches to obtain the input pytree
         (the batcher advances; use a fresh engine if that matters).
         """
+        return self.lower(chunk, start).compile().as_text()
+
+    def lower(self, chunk: Optional[int] = None, start: int = 0):
+        """Lower one ``chunk``-round superstep starting at round ``start``
+        to a ``jax.stages.Lowered`` (its ``as_text()`` is the StableHLO
+        that XLA is handed)."""
         k = chunk or self.chunk or self.cfg.eval_every
         rnds = jnp.arange(start, start + k)
         carry = (self._params, self._opt_state, self.gstate, self.sim,
                  self._netstate, self._resid, self._hat)
         if self.stream is None:
             batches = self._prefetch_batches(k)
-            lowered = self._get_superstep(batches).lower(
-                carry, rnds, batches)
-        else:
-            lowered = self._get_superstep(None).lower(
-                carry, rnds, *self._stream_args)
-        return lowered.compile().as_text()
+            return self._get_superstep(batches).lower(carry, rnds, batches)
+        return self._get_superstep(None).lower(
+            carry, rnds, *self._stream_args)
 
     def _run_chunk(self, start: int, end: int) -> np.ndarray:
         """Execute rounds ``[start, end]`` as one on-device superstep and

@@ -8,11 +8,13 @@ so the same model stacks on a node axis and flows through
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def _conv_init(key, shape, dtype=jnp.float32):
@@ -73,9 +75,70 @@ def _conv(p, x):
     return y + p["b"]
 
 
-def _pool(x):
+def _max_pool(x):
     return jax.lax.reduce_window(
         x, -jnp.inf, jax.lax.max, (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
+
+
+def _pool(x):
+    """2x2 / stride-2 / VALID max-pool of ``x [b, H, W, C]``.
+
+    The forward is ``lax.reduce_window(max)``.  The gradient goes to each
+    window's *first* maximum in row-major order over (h, w), and 0 to the
+    rest and to the row and column that VALID drops at an odd H or W:
+    the tie rule of XLA's ``select`` with ``ge``, which ``reduce_window``'s
+    own gradient uses, so ``dx`` is bitwise the same.  It is built from
+    slices and ``where`` instead of that gradient's
+    ``select_and_scatter_add``, which re-reads ``x`` in a memory-bound pass
+    of its own; the forward keeps only the window choice (int8).
+    """
+    return _first_max_pool(x, x.shape[1], x.shape[2])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _first_max_pool(x, h, w):
+    return _max_pool(x)
+
+
+def _window_choice(x):
+    """int8 ``[b, H//2, W//2, C]``: the row-major slot (0-3) of each
+    window's first maximum, by the chain of XLA's ``select`` with ``ge``
+    (a later element takes the slot only where the held one is not
+    ``>=`` it; NaN ordering included)."""
+    b, h, w, c = x.shape
+    ho, wo = h // 2, w // 2
+    xr = x[:, :2 * ho, :2 * wo].reshape(b, ho, 2, wo, 2, c)
+    best = xr[:, :, 0, :, 0]
+    choice = jnp.zeros(best.shape, jnp.int8)
+    for slot, (i, j) in enumerate(((0, 1), (1, 0), (1, 1)), 1):
+        cand = xr[:, :, i, :, j]
+        take = ~(best >= cand)
+        best = jnp.where(take, cand, best)
+        choice = jnp.where(take, jnp.int8(slot), choice)
+    return choice
+
+
+def _first_max_pool_fwd(x, h, w):
+    return _max_pool(x), _window_choice(x)
+
+
+# The row-major slot of each element of a window, laid out as the
+# windows of ``x.reshape(b, H//2, 2, W//2, 2, C)``.  A constant, not an
+# iota: built from an iota, XLA:CPU compiled the backward differently
+# in a one-round superstep than in longer ones, and the engines'
+# trajectories stopped being bitwise equal across superstep lengths.
+_WINDOW_SLOT = np.arange(4, dtype=np.int8).reshape(1, 1, 2, 1, 2, 1)
+
+
+def _first_max_pool_bwd(h, w, choice, g):
+    b, ho, wo, c = g.shape
+    dx = jnp.where(choice[:, :, None, :, None] == _WINDOW_SLOT,
+                   g[:, :, None, :, None], jnp.zeros((), g.dtype))
+    dx = dx.reshape(b, 2 * ho, 2 * wo, c)
+    return (jnp.pad(dx, ((0, 0), (0, h - 2 * ho), (0, w - 2 * wo), (0, 0))),)
+
+
+_first_max_pool.defvjp(_first_max_pool_fwd, _first_max_pool_bwd)
 
 
 def cnn_forward(p, images: jax.Array) -> jax.Array:
