@@ -36,7 +36,7 @@ def main(argv=None) -> int:
     import jax
     import jax.numpy as jnp
 
-    from chipbench import compare, data, faults, harness
+    from chipbench import compare, faults, harness
     cell = harness.load_cell(args.workload)
     harness.use_checkout_cache(ROOT)
     devs = jax.devices()
@@ -58,8 +58,7 @@ def main(argv=None) -> int:
 
         for seed in seeds:
             s = harness.sub_seeds(seed)
-            train, parts, test = data.build(s["data"], cell["model"],
-                                            cell["traffic"])
+            train, parts, test = harness.build_data(cell, s)
             t = time.perf_counter()
             ref, grad0, p0 = harness.reference_summary(
                 cell, s, train, parts, test)

@@ -1,18 +1,45 @@
 """One run of one benchmark cell, driven by data.
 
 A cell (an entry of ``workloads`` in ``BENCHMARK.json``) names a
-configuration (its file under ``configs``: the node model's sizes) and a
-traffic mix (``chipbench/traffic/<traffic>.json``: strategy, population,
-degree, data split, batch, cadence), and has its limits of
-``correct`` in ``chipbench/limits/<cell>.json``.  Per-layer metrics are
-read by ``chipbench/metrics/<metric>.py``.  A new cell, mix or metric is
-a new file; nothing here names one.
+configuration (its file under ``configs``: the node model's sizes and
+its ``family``) and a traffic mix (``chipbench/traffic/<traffic>.json``:
+strategy, population, degree, data split, batch, cadence), and has its
+limits of ``correct`` in ``chipbench/limits/<cell>.json``.  Per-layer
+metrics are read by ``chipbench/metrics/<metric>.py``.  The node model's
+family is ``chipbench/families/<family>.py`` with its reference half
+``<family>_reference.py``, found under the cell's own checkout.  A new
+cell, mix, metric or family is a new file; nothing here names one.
+
+A family's file supplies
+
+* ``build(seed, model, traffic) -> (train, parts, test)``: the data,
+  made on the device from the seed; ``parts`` are the per-node index
+  lists of the shared split (``chipbench/data.py``);
+* ``node(model) -> (init_fn, loss_fn, eval_fn)``, built from the
+  program's own modules, with the reference half's ``init_node`` as the
+  start, and ``batcher(train, parts, traffic, stream_seed)``, what
+  ``DecentralizedRunner`` is handed;
+* ``param_count(model)``, ``round_flops(model, traffic, edges,
+  negotiates)`` and ``eval_flops(model, traffic)``: model FLOPs from
+  shapes alone, multiply-adds times two, only the work a round requires.
+  The rules every family shares: the local step of every node on its
+  batch; mixing ``2 (edges + n) D`` a round, the edges in use that round
+  plus each node's own model; one Eq.-3 Gram ``2 n^2 D`` a negotiating
+  round (the strategy reads the similarity only when it negotiates);
+  every node's forward over the test set an evaluation.
+
+Its reference half supplies ``init_node(key, model)``,
+``loss_and_accuracy(p, batch, model)`` in plain ``jax.numpy`` at
+``Precision.HIGHEST``, and ``draw(train, rows, sizes, key, rnd, batch)``,
+the batches the program's stream draws; it imports nothing of the
+program.
 
 The run is built as a user builds it: ``DecentralizedRunner`` with
-``RunnerConfig(compiled=True)``, the configuration's node model built
-from the program's layer functions (``chipbench/model.py``), ``sgd(lr)`` and a ``DeviceDataStream`` over the device-made train
-set.  ``DecentralizedRunner.run`` drives the compiled engine's segments:
-a superstep of ``eval_every`` rounds, then ``evaluate`` on the test set.
+``RunnerConfig(compiled=True)``, the family's node and batcher and
+``sgd(lr)``; a cell on four chips shards its population over them
+(``RunnerConfig(mesh_devices=4)``), the reference stays on one device.
+``DecentralizedRunner.run`` drives the compiled engine's segments: a
+superstep of ``eval_every`` rounds, then ``evaluate`` on the test set.
 
 * Set-up: data, weights and runner; round 0 (its own K = 1 superstep)
   and its evaluation; the first whole segment.  These are the rounds the
@@ -28,7 +55,6 @@ a superstep of ``eval_every`` rounds, then ``evaluate`` on the test set.
 """
 from __future__ import annotations
 
-import functools
 import gc
 import importlib.util
 import json
@@ -51,6 +77,7 @@ TRACE_SEGMENTS = 3        # whole segments traced
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT = "/jax/compilation_cache/cache_hits"
 STREAM_SEED = 0
+_MODULES = {}             # path -> module, see load_module
 
 
 def load_json(path: Path):
@@ -82,6 +109,39 @@ def load_cell(name: str, root: Path = ROOT) -> dict:
                        if name in m.get("workloads", [name])],
         "root": root,
     }
+
+
+def load_module(path: Path):
+    """The Python file at ``path`` as a module, run once per process: a
+    family's file and the reference share one reference half."""
+    path = Path(path).resolve()
+    if path not in _MODULES:
+        spec = importlib.util.spec_from_file_location(
+            f"chipbench.{path.parent.name}.{path.stem}", path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        _MODULES[path] = mod
+    return _MODULES[path]
+
+
+def load_family(name: str, root: Path = ROOT) -> tuple:
+    """The node-model family ``name`` of the checkout at ``root``: its
+    file ``chipbench/families/<name>.py`` and its reference half
+    ``<name>_reference.py``."""
+    folder = root / "chipbench" / "families"
+    return (load_module(folder / f"{name}.py"),
+            load_module(folder / f"{name}_reference.py"))
+
+
+def cell_family(cell: dict) -> tuple:
+    """:func:`load_family` of the cell's configuration."""
+    return load_family(cell["model"]["family"], cell["root"])
+
+
+def build_data(cell: dict, seeds: dict):
+    """The cell's ``(train, parts, test)``, made by its family."""
+    return cell_family(cell)[0].build(seeds["data"], cell["model"],
+                                      cell["traffic"])
 
 
 def use_checkout_cache(root: Path = ROOT) -> None:
@@ -130,18 +190,11 @@ class CompileCounter:
 def make_runner(cell: dict, seeds: dict, train, parts, test):
     """The cell's ``DecentralizedRunner``, built as a user builds it."""
     from repro.core import InGraphEpidemicStrategy, InGraphMorphStrategy
-    from repro.data import DeviceDataStream
     from repro.dlrt import DecentralizedRunner, RunnerConfig
     from repro.optim import sgd
 
-    from chipbench import model as node_model
-    from chipbench import reference
-    import jax
-
-    model, t = cell["model"], cell["traffic"]
-    if cell["chips"] != 1:
-        raise ValueError(f"{cell['name']} asks for {cell['chips']} chips; "
-                         "the harness runs a cell's population on one")
+    family, _ = cell_family(cell)
+    t = cell["traffic"]
     n = t["nodes"]
     if t["strategy"] == "morph":
         strategy = InGraphMorphStrategy(n=n, k=t["k"], view_size=t["k"] + 2,
@@ -152,19 +205,17 @@ def make_runner(cell: dict, seeds: dict, train, parts, test):
                                            seed=seeds["strategy"])
     else:
         raise ValueError(f"unknown strategy {t['strategy']!r}")
-    net = reference.arch(model)
-    init = jax.jit(functools.partial(reference.init_node, arch=net))
-    loss = node_model.loss_fn(net)
+    init, loss, evaluate = family.node(cell["model"])
     return DecentralizedRunner(
-        init_fn=init, loss_fn=loss, eval_fn=loss,
+        init_fn=init, loss_fn=loss, eval_fn=evaluate,
         optimizer=sgd(t["lr"]),
-        batcher=DeviceDataStream(train, parts, t["batch"],
-                                 seed=seeds["stream"]),
+        batcher=family.batcher(train, parts, t, seeds["stream"]),
         test_batch=test, strategy=strategy,
         cfg=RunnerConfig(
             n_nodes=n, rounds=SEGMENTS_PLANNED * t["eval_every"],
             eval_every=t["eval_every"], seed=seeds["init"], compiled=True,
-            eval_batch_chunk=t["eval_chunk"]))
+            eval_batch_chunk=t["eval_chunk"],
+            mesh_devices=cell["chips"] if cell["chips"] > 1 else None))
 
 
 def segment_failed(traffic: dict, rec, edges) -> bool:
@@ -288,28 +339,23 @@ def peak_lookup(kind: str):
 
 
 def read_metric(name: str, ctx: dict):
-    path = HERE / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench.metrics.{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read(ctx)
+    return load_module(HERE / "metrics" / f"{name}.py").read(ctx)
 
 
 def traced_flops(cell, runner, first, last) -> float:
     """Model FLOPs of window segments ``first..last`` (1-based)."""
-    from chipbench import flops
+    family, _ = cell_family(cell)
     t, every = cell["traffic"], cell["traffic"]["eval_every"]
     total = 0.0
     for seg in range(first, last + 1):
         r0 = every * seg + 1              # segment 1: rounds every+1..2every
         for rnd in range(r0, r0 + every):
             edges = int(np.asarray(runner.engine.edge_history[rnd]).sum())
-            total += flops.round_flops(
-                cell["model"], t["nodes"], t["batch"], edges,
+            total += family.round_flops(
+                cell["model"], t, edges,
                 negotiates=t["strategy"] == "morph"
                 and rnd % t["delta_r"] == 0)
-        total += flops.eval_flops(cell["model"], t["nodes"], t["test"])
+        total += family.eval_flops(cell["model"], t)
     return total
 
 
@@ -386,7 +432,8 @@ def reference_summary(cell, seeds, train, parts, test, dtype=None):
 
     from chipbench import compare, reference
     t = cell["traffic"]
-    ref = reference.run(seeds=seeds, model=cell["model"], traffic=t,
+    ref = reference.run(seeds=seeds, family=cell_family(cell)[1],
+                        model=cell["model"], traffic=t,
                         train=train, parts=parts, test=test,
                         rounds=t["eval_every"] + 1,
                         dtype=jnp.float32 if dtype is None else dtype)
@@ -402,7 +449,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     point makes that)."""
     import jax
 
-    from chipbench import compare, data
+    from chipbench import compare
 
     if cell["limits"] is None:
         raise RuntimeError(f"no limits for {cell['name']}: "
@@ -411,9 +458,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     seeds = sub_seeds(seed)
     marks = [("start", t0), ("backend", time.perf_counter())]
     with jax.profiler.TraceAnnotation("chipbench.setup"):
-        train, parts, test = data.build(seeds["data"], cell["model"],
-                                        cell["traffic"])
-        jax.block_until_ready(train.images)
+        train, parts, test = build_data(cell, seeds)
+        jax.block_until_ready(train)
         marks.append(("data", time.perf_counter()))
         runner = make_runner(cell, seeds, train, parts, test)
         marks.append(("runner", time.perf_counter()))

@@ -4,16 +4,22 @@ Straightforward ``jax.numpy`` with every contraction at
 ``Precision.HIGHEST``, one round at a time from Python, the deferred
 acceptance matching as a sequential loop in NumPy.  It imports nothing of
 the program under test and takes nothing the program made: it starts from
-the benchmark's own seeded weights (:func:`init_node`, the same function
-the benchmark hands the program as ``init_fn``) and the benchmark's data,
-and redraws every random choice (batch rows, Gumbel noise, tie noise,
-Epidemic peers) from the seeds by the recipes the paper's engine states.
+the benchmark's own seeded weights (the family's ``init_node``, the same
+function the benchmark hands the program as ``init_fn``) and the
+benchmark's data, and redraws every random choice (batch rows, Gumbel
+noise, tie noise, Epidemic peers) from the seeds by the recipes the
+paper's engine states.
+
+The node model is the reference half of the configuration's family
+(``chipbench/families/<family>_reference.py``): ``init_node(key, model)``,
+``loss_and_accuracy(p, batch, model)`` and ``draw(train, rows, sizes, key,
+rnd, batch)``, the batches the program's stream draws.  Everything here
+works on any parameter pytree.
 
 One round, for every node ``i`` (population stacked on a leading axis):
 
-1. local step: ``p_i <- p_i - lr * grad L(p_i; batch_i)``, the
-   configuration's layers in order (``layers``: convolutions, GroupNorm,
-   ReLU, 2x2 max-pool, linear), mean cross-entropy over the batch;
+1. local step: ``p_i <- p_i - lr * grad L(p_i; batch_i)``, the family's
+   loss over the node's drawn batch;
 2. Morph, every ``delta_r`` rounds: the Eq.-3 similarity (per-leaf
    cosine, averaged over leaves) of the post-step models, Eq.-4
    transitive estimates, Gumbel-top-k selection of ``k`` dissimilar
@@ -29,7 +35,7 @@ from __future__ import annotations
 
 import collections
 import functools
-import math
+import json
 
 import jax
 import jax.numpy as jnp
@@ -38,152 +44,51 @@ import numpy as np
 HIGHEST = jax.lax.Precision.HIGHEST
 NEG_INF = -1e30
 TIE_NOISE = 1e-4          # uniform noise breaking preference ties
-GN_EPS = 1e-5
 
 
-# ---------------------------------------------------------------------------
-# The node model, from the configuration's layer list
-# ---------------------------------------------------------------------------
+class Model(dict):
+    """A configuration as a static argument of a jitted function."""
 
-def arch(model: dict) -> tuple:
-    """The node model of a configuration as a hashable (static) value:
-    ``(image_size, in_channels, layers)``."""
-    return (model["image_size"], model["in_channels"],
-            tuple(tuple(layer) for layer in model["layers"]))
+    def __hash__(self):
+        return hash(json.dumps(self, sort_keys=True))
 
 
-def param_layers(arch: tuple) -> list:
-    """``[(name, kind, shapes)]`` for each layer of the architecture
-    that holds weights, in order, with the shapes of its leaves: a conv
-    ``["conv", out, k]`` (``k x k``, SAME, with bias) is ``conv<i>``, a
-    ``["group_norm", groups]`` ``gn<i>``, a ``["dense", out]`` ``fc<i>``.
-    ``relu``, ``pool`` (2x2 max, stride 2) and ``flatten`` hold none."""
-    h, c, layers = arch
-    feat, out, seen = None, [], collections.Counter()
-    for layer in layers:
-        kind = layer[0]
-        if kind == "conv":
-            cout, k = layer[1], layer[2]
-            seen["conv"] += 1
-            out.append((f"conv{seen['conv']}", kind,
-                        {"w": (k, k, c, cout), "b": (cout,)}))
-            c = cout
-        elif kind == "group_norm":
-            seen["gn"] += 1
-            out.append((f"gn{seen['gn']}", kind,
-                        {"scale": (c,), "bias": (c,)}))
-        elif kind == "pool":
-            h //= 2
-        elif kind == "flatten":
-            feat = h * h * c
-        elif kind == "dense":
-            seen["fc"] += 1
-            out.append((f"fc{seen['fc']}", kind,
-                        {"w": (feat, layer[1]), "b": (layer[1],)}))
-            feat = layer[1]
-        elif kind != "relu":
-            raise ValueError(f"unknown layer {layer!r}")
-    return out
-
-
-def init_node(key, arch: tuple):
-    """One node's weights: He-scaled truncated normals for the convs,
-    ``1/sqrt(fan_in)`` for the linear layers, zero biases, unit GroupNorm
-    scales.  The benchmark gives this same function to the program as
-    its ``init_fn``, so both start from the same seeded weights."""
-    layers = param_layers(arch)
-    keys = jax.random.split(key, len(layers))
-    params = {}
-    for k, (name, kind, shapes) in zip(keys, layers):
-        if kind == "group_norm":
-            params[name] = {"scale": jnp.ones(shapes["scale"], jnp.float32),
-                            "bias": jnp.zeros(shapes["bias"], jnp.float32)}
-            continue
-        w = shapes["w"]
-        fan_in = math.prod(w[:-1])
-        std = math.sqrt((2.0 if kind == "conv" else 1.0) / fan_in)
-        params[name] = {"w": jax.random.truncated_normal(
-            k, -2.0, 2.0, w, jnp.float32) * std,
-            "b": jnp.zeros(shapes["b"], jnp.float32)}
-    return params
-
-
-def _dtype(p):
+def leaf_dtype(p):
+    """The dtype of a parameter pytree, which sets the arithmetic."""
     return jax.tree_util.tree_leaves(p)[0].dtype
 
 
-def _prec(dtype):
+def precision(dtype):
+    """HIGHEST for float32; the default for the bfloat16 control."""
     return HIGHEST if dtype == jnp.float32 else None
 
 
-def forward(p, x, arch: tuple):
-    """Logits ``[b, classes]`` of images ``x [b, H, W, C]`` through the
-    layers of ``arch``; the dtype of ``p`` sets the arithmetic."""
-    prec = _prec(_dtype(p))
-    names = iter(name for name, *_ in param_layers(arch))
-    h = x
-    for layer in arch[2]:
-        kind = layer[0]
-        if kind == "conv":
-            q = p[next(names)]
-            h = jax.lax.conv_general_dilated(
-                h, q["w"], (1, 1), "SAME",
-                dimension_numbers=("NHWC", "HWIO", "NHWC"),
-                precision=prec) + q["b"]
-        elif kind == "group_norm":
-            q, groups = p[next(names)], layer[1]
-            b, hh, ww, c = h.shape
-            g = h.reshape(b, hh, ww, groups, c // groups)
-            mu = g.mean(axis=(1, 2, 4), keepdims=True)
-            var = ((g - mu) ** 2).mean(axis=(1, 2, 4), keepdims=True)
-            g = (g - mu) / jnp.sqrt(var + GN_EPS)
-            h = g.reshape(b, hh, ww, c) * q["scale"] + q["bias"]
-        elif kind == "relu":
-            h = jnp.maximum(h, 0)
-        elif kind == "pool":
-            h = jax.lax.reduce_window(h, -jnp.inf, jax.lax.max,
-                                      (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
-        elif kind == "flatten":
-            h = h.reshape(h.shape[0], -1)
-        else:
-            q = p[next(names)]
-            h = jnp.dot(h, q["w"], precision=prec) + q["b"]
-    return h
-
-
-def loss_and_correct(p, images, labels, arch: tuple):
-    """Mean cross-entropy and the number of correct predictions."""
-    logits = forward(p, images.astype(_dtype(p)), arch)
-    logp = jax.nn.log_softmax(logits)
-    nll = -jnp.take_along_axis(logp, labels[:, None], axis=1)[:, 0]
-    return nll.mean(), (logits.argmax(-1) == labels).sum()
+def _cast(tree, dtype):
+    """Floating leaves of ``tree`` in ``dtype``; the others as they are."""
+    return jax.tree_util.tree_map(
+        lambda x: x.astype(dtype) if jnp.issubdtype(x.dtype, jnp.floating)
+        else x, tree)
 
 
 # ---------------------------------------------------------------------------
 # One round's pieces (jitted; n, k and shapes are static)
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("arch", "batch", "lr"))
-def local_step(params, data_images, data_labels, rows, sizes, key, rnd,
-               *, arch, batch, lr):
-    """Every node's SGD step on its round-``rnd`` batch: node ``i`` draws
-    ``batch`` rows uniformly, with replacement, from the first
-    ``sizes[i]`` entries of its row table ``rows[i]``, by
-    ``randint(fold_in(fold_in(key, rnd), i))``.  Returns the new params
-    and the gradients."""
-    k_round = jax.random.fold_in(key, rnd)
+@functools.partial(jax.jit,
+                   static_argnames=("family", "model", "batch", "lr"))
+def local_step(params, train, rows, sizes, key, rnd, *, family, model,
+               batch, lr):
+    """Every node's SGD step on its round-``rnd`` batch, which the
+    family draws from the node's row table ``rows[i]`` (its first
+    ``sizes[i]`` entries).  Returns the new params and the gradients."""
+    drawn = family.draw(train, rows, sizes, key, rnd, batch)
 
-    def one(p, table, size, i):
-        take = jax.random.randint(jax.random.fold_in(k_round, i),
-                                  (batch,), 0, size)
-        sel = table[take]
-        g = jax.grad(lambda q: loss_and_correct(
-            q, data_images[sel], data_labels[sel], arch)[0])(p)
-        lr_ = jnp.asarray(lr, _dtype(p))
+    def one(p, b):
+        g = jax.grad(lambda q: family.loss_and_accuracy(q, b, model)[0])(p)
+        lr_ = jnp.asarray(lr, leaf_dtype(p))
         return jax.tree_util.tree_map(lambda a, b: a - lr_ * b, p, g), g
 
-    n = rows.shape[0]
-    return jax.vmap(one)(params, rows, sizes, jnp.arange(n))
+    return jax.vmap(one)(params, drawn)
 
 
 @jax.jit
@@ -192,7 +97,7 @@ def similarity(params):
     pair of node models."""
     leaves = jax.tree_util.tree_leaves(params)
     n = leaves[0].shape[0]
-    prec = _prec(leaves[0].dtype)
+    prec = precision(leaves[0].dtype)
     total = 0.0
     for leaf in leaves:
         x = leaf.reshape(n, -1)
@@ -212,18 +117,18 @@ def mix(params, edges):
 
     def one(leaf):
         x = leaf.reshape(n, -1)
-        out = jnp.dot(w.astype(x.dtype), x, precision=_prec(x.dtype))
+        out = jnp.dot(w.astype(x.dtype), x, precision=precision(x.dtype))
         return out.reshape(leaf.shape).astype(leaf.dtype)
 
     return jax.tree_util.tree_map(one, params)
 
 
-@functools.partial(jax.jit, static_argnames=("arch",))
-def evaluate(params, images, labels, *, arch):
+@functools.partial(jax.jit, static_argnames=("family", "model"))
+def evaluate(params, test, *, family, model):
     """Per node (one node at a time): mean test loss and accuracy."""
     def one(p):
-        loss, correct = loss_and_correct(p, images, labels, arch)
-        return loss.astype(jnp.float32), correct / labels.shape[0]
+        loss, acc = family.loss_and_accuracy(p, test, model)
+        return loss.astype(jnp.float32), acc
     return jax.lax.map(one, params)
 
 
@@ -372,10 +277,12 @@ def morph_negotiate(st, params, *, k, view, beta):
 # The run
 # ---------------------------------------------------------------------------
 
-def run(*, seeds, model, traffic, train, parts, test, rounds,
+def run(*, seeds, family, model, traffic, train, parts, test, rounds,
         dtype=jnp.float32):
     """``rounds`` rounds from the seeded start, evaluated after the first
-    and the last.  ``seeds`` holds the integer seeds of the weights
+    and the last.  ``family`` is the reference half of the
+    configuration's family, ``train`` and ``test`` the pytrees its
+    ``build`` made.  ``seeds`` holds the integer seeds of the weights
     (``init``), the strategy (``strategy``) and the batch draws
     (``stream``).  Returns host arrays: ``p0`` (start), ``p1`` (after
     round 0), ``p_end``, ``grad0`` (the norm of each leaf of round 0's gradients
@@ -384,8 +291,9 @@ def run(*, seeds, model, traffic, train, parts, test, rounds,
     round 0 and after the last round."""
     n = traffic["nodes"]
     k = min(traffic["k"], n - 1)
-    net = arch(model)
-    init = jax.jit(jax.vmap(functools.partial(init_node, arch=net)))
+    model = Model(model)
+    init = jax.jit(jax.vmap(functools.partial(family.init_node,
+                                              model=model)))
     p = init(jax.random.split(jax.random.PRNGKey(seeds["init"]), n))
     p0 = jax.device_get(p)
     p = jax.tree_util.tree_map(lambda x: x.astype(dtype), p)
@@ -393,17 +301,16 @@ def run(*, seeds, model, traffic, train, parts, test, rounds,
     rows = jnp.asarray(np.stack([np.resize(q, size) for q in parts])
                        .astype(np.int32))
     sizes = jnp.asarray([len(q) for q in parts], jnp.int32)
-    images = train.images.astype(dtype)
-    test_images = test["images"].astype(dtype)
+    train, test = _cast(train, dtype), _cast(test, dtype)
     stream_key = jax.random.PRNGKey(seeds["stream"])
     strat_key = jax.random.PRNGKey(seeds["strategy"])
     morph = traffic["strategy"] == "morph"
     st = morph_init(strat_key, n) if morph else None
     out = {"p0": p0, "edges": [], "evals": []}
     for rnd in range(rounds):
-        p, g = local_step(p, images, train.labels, rows, sizes, stream_key,
-                          rnd, arch=net, batch=traffic["batch"],
-                          lr=traffic["lr"])
+        p, g = local_step(p, train, rows, sizes, stream_key, rnd,
+                          family=family, model=model,
+                          batch=traffic["batch"], lr=traffic["lr"])
         if morph:
             if rnd % traffic["delta_r"] == 0:
                 st = morph_negotiate(st, p, k=k,
@@ -414,8 +321,7 @@ def run(*, seeds, model, traffic, train, parts, test, rounds,
         p = mix(p, edges)
         out["edges"].append(np.asarray(edges))
         if rnd in (0, rounds - 1):
-            losses, acc = evaluate(p, test_images, test["labels"],
-                                   arch=net)
+            losses, acc = evaluate(p, test, family=family, model=model)
             out["evals"].append((np.asarray(losses, np.float64),
                                  np.asarray(acc, np.float64)))
             snap = jax.tree_util.tree_map(
@@ -426,3 +332,14 @@ def run(*, seeds, model, traffic, train, parts, test, rounds,
                 lambda x: float(jnp.linalg.norm(x.astype(jnp.float32))), g)
     out["edges"] = np.stack(out["edges"])
     return out
+
+
+def __getattr__(name):
+    """``arch`` and ``init_node(key, arch)`` of the CNN family under their
+    former names here, for callers outside the benchmark
+    (``tests/test_pool_grad.py``)."""
+    if name in ("arch", "init_node"):
+        from chipbench.harness import load_family
+        cnn = load_family("cnn")[1]
+        return cnn.arch if name == "arch" else cnn.init_arch
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -1,13 +1,17 @@
 """``correct`` at a size a test run holds, on the CPU.
 
 A tiny cell is added as files alone (its configuration, traffic mix and
-limits in a checkout of its own), and the whole run is driven past the
-chip check: a sound run comes out correct; the bfloat16 control in the
-program's place, and each fault planted in the timed path underneath,
-come out not correct.  The limits of the tiny cells are set from the CPU,
-where the program and the reference both compute in f32 (they agree to
-about 1e-7)."""
+limits in a checkout of its own, beside a copy of the families), and the
+whole run is driven past the chip check: a sound run comes out correct;
+the bfloat16 control in the program's place, and each fault planted in
+the timed path underneath, come out not correct; a cell on four chips
+runs its population sharded over four devices and comes out correct.
+The limits of the tiny cells are set from the CPU, where the program and
+the reference both compute in f32 (they agree to about 1e-7)."""
 import json
+import os
+import shutil
+import subprocess
 import sys
 import time
 from pathlib import Path
@@ -20,19 +24,25 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 from chipbench import compare, faults, harness, reference  # noqa: E402
 
+HERE = Path(__file__).resolve().parent
+
 LIMITS = {"loss0": 1e-4, "loss": 1e-4, "acc0": 0.02, "acc": 0.02,
           "grad": 1e-3, "change": 1e-3, "edges": 0}
 SEED = 2**32 + 5         # wider than 32 bits, as a run's seed may be
 
 
-def write_cells(root: Path):
-    """A checkout with two tiny cells, Morph and Epidemic, added as
-    files."""
+def write_cells(root: Path, chips: int = 1):
+    """A checkout with two tiny cells on ``chips`` chips, Morph and
+    Epidemic, added as files."""
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     (root / "chipbench" / "traffic").mkdir(parents=True)
     (root / "chipbench" / "limits").mkdir(parents=True)
+    shutil.copytree(ROOT / "chipbench" / "families",
+                    root / "chipbench" / "families",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     (root / "tiny.json").write_text(json.dumps({
-        "name": "tiny", "in_channels": 3, "num_classes": 10,
+        "name": "tiny", "family": "cnn", "in_channels": 3,
+        "num_classes": 10,
         "image_size": 8,
         "layers": [["conv", 4, 5], ["pool"], ["relu"], ["group_norm", 2],
                    ["conv", 8, 3], ["relu"], ["pool"], ["flatten"],
@@ -50,7 +60,7 @@ def write_cells(root: Path):
         (root / "chipbench" / "limits" / f"{name}.json").write_text(
             json.dumps(LIMITS))
         bench["workloads"].append({"name": name, "config": "tiny",
-                                   "traffic": name, "chips": 1,
+                                   "traffic": name, "chips": chips,
                                    "why": "test"})
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
 
@@ -90,10 +100,8 @@ def test_planted_fault_is_not_correct(tiny, fault):
 def test_bfloat16_control_is_not_correct(tiny):
     cell = harness.load_cell("tiny-morph", tiny)
     seeds = harness.sub_seeds(SEED)
-    from chipbench import data
     import jax.numpy as jnp
-    train, parts, test = data.build(seeds["data"], cell["model"],
-                                    cell["traffic"])
+    train, parts, test = harness.build_data(cell, seeds)
     ref, grad0, _ = harness.reference_summary(cell, seeds, train, parts,
                                               test)
     ctrl, _, _ = harness.reference_summary(cell, seeds, train, parts, test,
@@ -119,10 +127,52 @@ def test_reference_matching_equals_the_engines_fixpoint():
             assert (want == got).all()
 
 
-def test_a_cell_on_more_chips_is_refused(tiny):
-    cell = dict(harness.load_cell("tiny-morph", tiny), chips=4)
-    with pytest.raises(ValueError, match="4 chips"):
-        harness.make_runner(cell, harness.sub_seeds(SEED), None, None, None)
+FOUR_CHIPS = """
+import json, sys, time
+from pathlib import Path
+sys.path[:0] = sys.argv[2:]
+import jax
+from chipbench import harness
+from test_chipbench_correct import SEED, write_cells
+
+root = Path(sys.argv[1])
+write_cells(root, chips=4)
+runners = []
+make_runner = harness.make_runner
+harness.make_runner = lambda *a: runners.append(make_runner(*a)) or runners[-1]
+out = {}
+for name in ("tiny-morph", "tiny-epidemic"):
+    result = harness.run_cell(harness.load_cell(name, root), SEED, 0.05,
+                              False, time.perf_counter())
+    leaves = jax.tree_util.tree_leaves(runners[-1].engine.params)
+    out[name] = {"correct": result["correct"],
+                 "compared": result["compared"],
+                 "count": result["device"]["count"],
+                 "devices": [len(x.sharding.device_set) for x in leaves],
+                 "replicated": [x.sharding.is_fully_replicated
+                                for x in leaves]}
+print(json.dumps(out))
+"""
+
+
+def test_a_cell_on_four_chips_runs_sharded_and_correct(tmp_path):
+    """A ``chips: 4`` cell builds its runner with ``mesh_devices=4``: on
+    four forced CPU devices (in a process of its own, as XLA makes them
+    at start-up) every node-model leaf is split over the four, and the
+    run against the one-device reference comes out correct."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    proc = subprocess.run(
+        [sys.executable, "-c", FOUR_CHIPS, str(tmp_path / "checkout"),
+         str(ROOT), str(ROOT / "src"), str(HERE)],
+        env=env, capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    for name, run in out.items():
+        assert run["correct"], (name, run["compared"])
+        assert run["count"] == 4
+        assert set(run["devices"]) == {4}
+        assert not any(run["replicated"])
 
 
 def test_the_timed_model_matches_the_reference_forward(tiny):
@@ -131,10 +181,11 @@ def test_the_timed_model_matches_the_reference_forward(tiny):
     import jax
     import jax.numpy as jnp
 
-    from chipbench import model as node_model
-    net = reference.arch(harness.load_cell("tiny-morph", tiny)["model"])
-    p = reference.init_node(jax.random.PRNGKey(3), net)
+    cnn, cnn_reference = harness.load_family("cnn", tiny)
+    model = harness.load_cell("tiny-morph", tiny)["model"]
+    net = cnn_reference.arch(model)
+    p = cnn_reference.init_node(jax.random.PRNGKey(3), model)
     x = jax.random.normal(jax.random.PRNGKey(4), (5, 8, 8, 3), jnp.float32)
-    np.testing.assert_allclose(node_model.forward(p, x, net),
-                               reference.forward(p, x, net),
+    np.testing.assert_allclose(cnn.forward(p, x, net),
+                               cnn_reference.forward(p, x, net),
                                rtol=1e-5, atol=1e-5)
