@@ -359,21 +359,34 @@ def traced_flops(cell, runner, first, last) -> float:
     return total
 
 
-def layer_context(tr, window, chips, **counts) -> dict:
+def layer_context(tr, window, chips, stages=None, **counts) -> dict:
     """What a per-layer reader is handed: the trace cut to the cell's own
     ``chips`` devices (the machine may show more), the traced window, the
-    cell's chip count, and ``counts`` (``rounds``, ``evals``, ``flops``,
-    ``peak``)."""
+    cell's chip count, ``stages``, the superstep's ``{instruction name:
+    stage}`` (:func:`chipbench.trace.op_stages`; empty where not given),
+    and ``counts`` (``rounds``, ``evals``, ``flops``, ``peak``)."""
     return dict(counts, trace=dict(tr, devices=tr["devices"][:chips]),
-                window=window, chips=chips)
+                window=window, chips=chips, stages=stages or {})
+
+
+def superstep_stages(runner) -> dict:
+    """``{instruction name: stage}`` of the window's superstep, from its
+    post-optimization HLO (compiled again after the window, which the
+    persistent cache serves); the stages are the program's own scope
+    names, ``repro.dlrt.compiled.STAGES``."""
+    from repro.dlrt.compiled import STAGES
+
+    from chipbench import trace
+    return trace.op_stages(runner.engine.compiled_hlo(), STAGES)
 
 
 def per_layer(cell, runner, win) -> tuple:
     """The per-layer metrics, ``device`` additions and the breakdown,
-    from the traced segments."""
+    from the traced segments and the superstep's stage map."""
     import jax
 
     from chipbench import trace
+    stages = superstep_stages(runner)
     tr = trace.load(win.trace_dir)
     shutil.rmtree(win.trace_dir, ignore_errors=True)
     span = trace.host_span(tr["host"], "chipbench.traced")
@@ -383,7 +396,7 @@ def per_layer(cell, runner, win) -> tuple:
     first, last = win.traced
     every = cell["traffic"]["eval_every"]
     kind = jax.devices()[0].device_kind
-    ctx = layer_context(tr, (lo, hi), cell["chips"],
+    ctx = layer_context(tr, (lo, hi), cell["chips"], stages,
                         rounds=every * (last - first + 1),
                         evals=last - first + 1,
                         flops=traced_flops(cell, runner, first, last),
@@ -397,7 +410,12 @@ def per_layer(cell, runner, win) -> tuple:
             metrics[name] = {"value": value, "unit": units[name]}
     devs = ctx["trace"]["devices"]
     busy = sum(trace.busy_ns(d, lo, hi) for d in devs) / len(devs) / 1e9
-    breakdown = {"device_ops": trace.top_ops(devs, lo, hi),
+    times = [trace.stage_times(d, stages, lo, hi) for d in devs]
+    total = sum(sum(t.values()) for t in times)
+    unstaged = sum(t.get(None, 0) for t in times)
+    print(f"stages: {1 - unstaged / max(total, 1):.4%} of the superstep's "
+          "op time has a stage", file=sys.stderr)
+    breakdown = {"device_ops": trace.top_ops(devs, lo, hi, stages=stages),
                  "idle_gaps": trace.idle_gaps(devs[0], tr["host"], lo, hi)}
     return metrics, {"busy_s": busy, "window_s": (hi - lo) / 1e9}, breakdown
 
@@ -425,9 +443,11 @@ def program_summary(cell, p0, win):
         cell["traffic"]["lr"])
 
 
-def reference_summary(cell, seeds, train, parts, test, dtype=None):
+def reference_summary(cell, seeds, train, parts, test, dtype=None,
+                      block=None):
     """The reference's run over the compared rounds: ``(summary, the
-    norms of its round-0 gradients, the start)``."""
+    norms of its round-0 gradients, the start)``.  ``block`` forces the
+    reference's nodes a block (tests)."""
     import jax.numpy as jnp
 
     from chipbench import compare, reference
@@ -436,7 +456,10 @@ def reference_summary(cell, seeds, train, parts, test, dtype=None):
                         model=cell["model"], traffic=t,
                         train=train, parts=parts, test=test,
                         rounds=t["eval_every"] + 1,
-                        dtype=jnp.float32 if dtype is None else dtype)
+                        dtype=jnp.float32 if dtype is None else dtype,
+                        block=block)
+    print(f"reference: {ref['block']} of {t['nodes']} nodes a block",
+          file=sys.stderr)
     summary = compare.summarise(
         ref["p0"], ref["p1"], ref["p_end"], ref["edges"],
         [(losses.mean(), acc) for losses, acc in ref["evals"]], t["lr"])

@@ -30,12 +30,22 @@ One round, for every node ``i`` (population stacked on a leading axis):
 
 ``dtype=bfloat16`` computes all of it in bfloat16 at the default matmul
 precision: the control that ``correct`` must reject.
+
+The population lives on the host, as NumPy in the run's dtype, between
+steps, so that a node model larger than a chip's share of its population
+can still be checked.  The device holds one block of ``b`` nodes at a
+time for the local step and for evaluation (:func:`block_size`: all
+``n`` wherever three copies of the population fit in half of the
+device's free memory), and one leaf's ``[n, ...]`` at a time for mixing
+and for the Eq.-3 Gram.  At ``b = n`` the arithmetic is that of one
+whole-population step.
 """
 from __future__ import annotations
 
 import collections
 import functools
 import json
+import math
 
 import jax
 import jax.numpy as jnp
@@ -74,14 +84,31 @@ def _cast(tree, dtype):
 # One round's pieces (jitted; n, k and shapes are static)
 # ---------------------------------------------------------------------------
 
+def block_size(n: int, node_bytes: int) -> int:
+    """Nodes in one block: all ``n`` where three copies of the population
+    (a step's params, new params and gradients) fit in half of the default
+    device's free memory, else the most that fit, at least 1.  A device
+    that reports no memory (the CPU) takes all ``n``."""
+    stats = jax.devices()[0].memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        return n
+    free = stats["bytes_limit"] - stats.get("bytes_in_use", 0)
+    return int(max(1, min(n, free // 2 // (3 * node_bytes))))
+
+
 @functools.partial(jax.jit,
                    static_argnames=("family", "model", "batch", "lr"))
-def local_step(params, train, rows, sizes, key, rnd, *, family, model,
-               batch, lr):
-    """Every node's SGD step on its round-``rnd`` batch, which the
-    family draws from the node's row table ``rows[i]`` (its first
-    ``sizes[i]`` entries).  Returns the new params and the gradients."""
-    drawn = family.draw(train, rows, sizes, key, rnd, batch)
+def local_step(params, train, rows, sizes, key, rnd, start, *, family,
+               model, batch, lr):
+    """The SGD step of the block of nodes ``start, start + 1, ...`` that
+    ``params`` holds, each on its round-``rnd`` batch, which the family
+    draws for every node from its row table ``rows[i]`` (its first
+    ``sizes[i]`` entries) and the block slices.  Returns the block's new
+    params and its gradients."""
+    count = jax.tree_util.tree_leaves(params)[0].shape[0]
+    drawn = jax.tree_util.tree_map(
+        lambda x: jax.lax.dynamic_slice_in_dim(x, start, count),
+        family.draw(train, rows, sizes, key, rnd, batch))
 
     def one(p, b):
         g = jax.grad(lambda q: family.loss_and_accuracy(q, b, model)[0])(p)
@@ -92,40 +119,55 @@ def local_step(params, train, rows, sizes, key, rnd, *, family, model,
 
 
 @jax.jit
+def _leaf_cos(leaf):
+    """``[n, n]`` cosine of every pair of rows of one leaf ``[n, ...]``."""
+    n = leaf.shape[0]
+    prec = precision(leaf.dtype)
+    x = leaf.reshape(n, -1)
+    norms = jnp.sqrt((x * x).sum(axis=1))
+    cos = jnp.dot(x, x.T, precision=prec) / jnp.maximum(
+        norms[:, None] * norms[None, :], 1e-12)
+    return cos.astype(jnp.float32)
+
+
 def similarity(params):
     """Eq. 3: ``[n, n]`` mean over leaves of the per-leaf cosine of every
-    pair of node models."""
+    pair of node models, one leaf on the device at a time."""
     leaves = jax.tree_util.tree_leaves(params)
-    n = leaves[0].shape[0]
-    prec = precision(leaves[0].dtype)
     total = 0.0
     for leaf in leaves:
-        x = leaf.reshape(n, -1)
-        norms = jnp.sqrt((x * x).sum(axis=1))
-        cos = jnp.dot(x, x.T, precision=prec) / jnp.maximum(
-            norms[:, None] * norms[None, :], 1e-12)
-        total = total + cos.astype(jnp.float32)
+        total = total + _leaf_cos(leaf)
     return total / len(leaves)
 
 
 @jax.jit
-def mix(params, edges):
+def _weights(edges):
     """Uniform averaging over each node and its in-neighbours."""
     n = edges.shape[0]
     w = edges.astype(jnp.float32) + jnp.eye(n, dtype=jnp.float32)
-    w = w / w.sum(axis=1, keepdims=True)
+    return w / w.sum(axis=1, keepdims=True)
 
-    def one(leaf):
-        x = leaf.reshape(n, -1)
-        out = jnp.dot(w.astype(x.dtype), x, precision=precision(x.dtype))
-        return out.reshape(leaf.shape).astype(leaf.dtype)
 
-    return jax.tree_util.tree_map(one, params)
+@jax.jit
+def _mix_leaf(w, leaf):
+    x = leaf.reshape(leaf.shape[0], -1)
+    out = jnp.dot(w.astype(x.dtype), x, precision=precision(x.dtype))
+    return out.reshape(leaf.shape).astype(leaf.dtype)
+
+
+def mix(params, edges):
+    """Uniform averaging over each node and its in-neighbours, in place
+    in the host population ``params``, one leaf on the device at a
+    time."""
+    w = _weights(edges)
+    for leaf in jax.tree_util.tree_leaves(params):
+        leaf[...] = np.asarray(_mix_leaf(w, leaf))
 
 
 @functools.partial(jax.jit, static_argnames=("family", "model"))
 def evaluate(params, test, *, family, model):
-    """Per node (one node at a time): mean test loss and accuracy."""
+    """Per node of the block ``params`` (one node at a time): mean test
+    loss and accuracy."""
     def one(p):
         loss, acc = family.loss_and_accuracy(p, test, model)
         return loss.astype(jnp.float32), acc
@@ -277,26 +319,50 @@ def morph_negotiate(st, params, *, k, view, beta):
 # The run
 # ---------------------------------------------------------------------------
 
+@jax.jit
+def _sum_squares(tree):
+    return jax.tree_util.tree_map(
+        lambda x: jnp.sum(jnp.square(x.astype(jnp.float32))), tree)
+
+
+def _put(population, start, block):
+    """Write the host ``block`` into rows ``start, ...`` of the host
+    ``population``, leaf by leaf."""
+    for dst, src in zip(jax.tree_util.tree_leaves(population),
+                        jax.tree_util.tree_leaves(block)):
+        dst[start:start + len(src)] = src
+
+
 def run(*, seeds, family, model, traffic, train, parts, test, rounds,
-        dtype=jnp.float32):
+        dtype=jnp.float32, block=None):
     """``rounds`` rounds from the seeded start, evaluated after the first
     and the last.  ``family`` is the reference half of the
     configuration's family, ``train`` and ``test`` the pytrees its
     ``build`` made.  ``seeds`` holds the integer seeds of the weights
     (``init``), the strategy (``strategy``) and the batch draws
-    (``stream``).  Returns host arrays: ``p0`` (start), ``p1`` (after
-    round 0), ``p_end``, ``grad0`` (the norm of each leaf of round 0's gradients
-    over all nodes), ``edges``
-    ``[rounds, n, n]``, and ``evals`` = [(losses [n], accuracy [n])] after
-    round 0 and after the last round."""
+    (``stream``).  ``block`` forces the nodes a block (tests); by default
+    :func:`block_size` sets it.  Returns host arrays: ``p0`` (start),
+    ``p1`` (after round 0), ``p_end``, ``grad0`` (the norm of each leaf
+    of round 0's gradients over all nodes), ``edges`` ``[rounds, n, n]``,
+    ``evals`` = [(losses [n], accuracy [n])] after round 0 and after the
+    last round, and ``block``, the nodes a block."""
     n = traffic["nodes"]
     k = min(traffic["k"], n - 1)
     model = Model(model)
-    init = jax.jit(jax.vmap(functools.partial(family.init_node,
-                                              model=model)))
-    p = init(jax.random.split(jax.random.PRNGKey(seeds["init"]), n))
-    p0 = jax.device_get(p)
-    p = jax.tree_util.tree_map(lambda x: x.astype(dtype), p)
+    init_one = functools.partial(family.init_node, model=model)
+    init = jax.jit(jax.vmap(init_one))
+    shapes = jax.eval_shape(init_one, jax.random.PRNGKey(0))
+    node_bytes = sum(math.prod(x.shape) for x in
+                     jax.tree_util.tree_leaves(shapes)) \
+        * jnp.dtype(dtype).itemsize
+    b = block or block_size(n, node_bytes)
+    starts = range(0, n, b)
+    keys = jax.random.split(jax.random.PRNGKey(seeds["init"]), n)
+    p0 = jax.tree_util.tree_map(
+        lambda x: np.empty((n,) + x.shape, x.dtype), shapes)
+    for s in starts:
+        _put(p0, s, jax.device_get(init(keys[s:s + b])))
+    p = jax.tree_util.tree_map(lambda x: x.astype(dtype), p0)
     size = max(len(q) for q in parts)
     rows = jnp.asarray(np.stack([np.resize(q, size) for q in parts])
                        .astype(np.int32))
@@ -306,11 +372,20 @@ def run(*, seeds, family, model, traffic, train, parts, test, rounds,
     strat_key = jax.random.PRNGKey(seeds["strategy"])
     morph = traffic["strategy"] == "morph"
     st = morph_init(strat_key, n) if morph else None
-    out = {"p0": p0, "edges": [], "evals": []}
+    out = {"p0": p0, "edges": [], "evals": [], "block": b}
     for rnd in range(rounds):
-        p, g = local_step(p, train, rows, sizes, stream_key, rnd,
-                          family=family, model=model,
-                          batch=traffic["batch"], lr=traffic["lr"])
+        squares = None
+        for s in starts:
+            new, g = local_step(
+                jax.tree_util.tree_map(lambda x: x[s:s + b], p), train,
+                rows, sizes, stream_key, rnd, s, family=family,
+                model=model, batch=traffic["batch"], lr=traffic["lr"])
+            _put(p, s, jax.device_get(new))
+            if rnd == 0:
+                sq = jax.device_get(_sum_squares(g))
+                squares = sq if squares is None else jax.tree_util.tree_map(
+                    np.add, squares, sq)
+            del new, g
         if morph:
             if rnd % traffic["delta_r"] == 0:
                 st = morph_negotiate(st, p, k=k,
@@ -318,18 +393,21 @@ def run(*, seeds, family, model, traffic, train, parts, test, rounds,
             edges = st.edges
         else:
             edges = epidemic_edges(strat_key, rnd, n=n, k=k)
-        p = mix(p, edges)
+        mix(p, edges)
         out["edges"].append(np.asarray(edges))
         if rnd in (0, rounds - 1):
-            losses, acc = evaluate(p, test, family=family, model=model)
-            out["evals"].append((np.asarray(losses, np.float64),
-                                 np.asarray(acc, np.float64)))
+            evals = [jax.device_get(evaluate(
+                jax.tree_util.tree_map(lambda x: x[s:s + b], p), test,
+                family=family, model=model)) for s in starts]
+            out["evals"].append(tuple(
+                np.concatenate([e[i] for e in evals]).astype(np.float64)
+                for i in (0, 1)))
             snap = jax.tree_util.tree_map(
-                lambda x: np.asarray(x, np.float32), p)
+                lambda x: np.array(x, np.float32), p)
             out["p1" if rnd == 0 else "p_end"] = snap
         if rnd == 0:
             out["grad0"] = jax.tree_util.tree_map(
-                lambda x: float(jnp.linalg.norm(x.astype(jnp.float32))), g)
+                lambda x: float(np.sqrt(x)), squares)
     out["edges"] = np.stack(out["edges"])
     return out
 
